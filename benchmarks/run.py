@@ -20,8 +20,10 @@ Paper mapping (NATSA, ICCD'20 / CS.AR'22 extended abstract):
                         harvest on the same engine sweep; k=4 gated <= 2.5x
                         the k=1 row in CI.
   bench_scaling       — Fig "speedup vs #PUs": anytime scheduler on 1..8
-                        SPMD workers (subprocess w/ forced device count);
-                        derived = parallel efficiency vs 1 worker.
+                        SPMD workers, meshes over this process's own
+                        devices (counts above jax.device_count() are
+                        skipped); derived = parallel efficiency vs 1
+                        worker.
   bench_anytime       — Fig "anytime convergence": profile error vs fraction
                         of rounds completed; derived = area-under-error.
   bench_partition     — Table "load balance": NATSA balanced partitioning vs
@@ -31,9 +33,8 @@ Paper mapping (NATSA, ICCD'20 / CS.AR'22 extended abstract):
                         data-movement reduction factor (the quantity NATSA's
                         energy win comes from).
   bench_precision     — mixed-precision gates: bf16-vs-f64 error-bound and
-                        epsilon-argmin rows, planted-motif exactness, and
-                        the compiled-kernel (jax.export TPU AOT) artifact
-                        rows; the bf16 throughput row itself rides
+                        epsilon-argmin rows and planted-motif exactness;
+                        the bf16 throughput row itself rides
                         bench_long_series so the >=1.5x ratio is an
                         interleaved same-loop A/B.
   bench_lm_train/decode — framework sanity: smoke-arch step latency.
@@ -43,7 +44,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -103,33 +103,22 @@ def bench_vs_baseline():
              f"speedup_vs_bf={t_bf/t_krn:.2f}x(interpret-mode)")
 
 
-_SCALING_SNIPPET = r"""
-import os, sys, time, json
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={P}"
-sys.path.insert(0, "{src}")
-import jax, numpy as np
-from repro.core.scheduler import AnytimeScheduler
-from repro.data.pipeline import random_walk
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh(({P},), ("workers",))
-ts = random_walk(6000, seed=2)
-sch = AnytimeScheduler(ts, 64, mesh, chunks_per_worker=4, band=64)
-sch.run(1)  # warmup one round
-t0 = time.perf_counter()
-sch.run()   # fused two-sided chunks: run() alone is the exact profile
-jax.block_until_ready(sch.state.profile.corr)
-print(json.dumps({{"t": time.perf_counter() - t0}}))
-"""
-
-
 def bench_scaling():
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
+    from repro.core.scheduler import AnytimeScheduler
+    from repro.launch.mesh import make_worker_mesh
+
+    ts = pipeline.random_walk(6000, seed=2)
     base = None
     for p in (1, 2, 4, 8):
-        code = _SCALING_SNIPPET.format(P=p, src=src)
-        out = subprocess.run([sys.executable, "-c", code],
-                             capture_output=True, text=True, timeout=600)
-        t = json.loads(out.stdout.strip().splitlines()[-1])["t"] * 1e6
+        if p > jax.device_count():
+            break
+        sch = AnytimeScheduler(ts, 64, make_worker_mesh(p),
+                               chunks_per_worker=4, band=64)
+        sch.run(1)  # warmup one round
+        t0 = time.perf_counter()
+        sch.run()   # fused two-sided chunks: run() alone is the exact profile
+        jax.block_until_ready(sch.state.profile.corr)
+        t = (time.perf_counter() - t0) * 1e6
         base = base or t
         eff = base / t / p
         emit(f"mp_scaling_workers{p}", t,
@@ -445,11 +434,9 @@ def bench_ckpt_overhead():
 
     from repro.core.faults import FaultPolicy
     from repro.core.scheduler import AnytimeScheduler
-    from repro.launch.mesh import compat_mesh
-
     n, m = 4096, 128
     ts = pipeline.random_walk(n, seed=51)
-    mesh = compat_mesh((1,), ("workers",))
+    mesh = jax.make_mesh((1,), ("workers",), (jax.sharding.AxisType.Auto,))
 
     def mk():
         return AnytimeScheduler(ts, m, mesh, chunks_per_worker=8, band=64)
@@ -597,9 +584,9 @@ def bench_bytes_proxy():
 
 
 def bench_precision():
-    """Mixed-precision error bounds + the compiled-kernel artifacts.
+    """Mixed-precision error bounds.
 
-    Three row families, all CI-gated:
+    Two row families, both CI-gated:
 
       * error bounds on the SAME n=16384 series the throughput gate uses:
         bf16-stream profile vs the f64 oracle (`precision="f64"` under
@@ -611,12 +598,7 @@ def bench_precision():
         (gate >= 0.99 on smooth data; strict index agreement rides the
         derived column for visibility);
       * planted-motif exactness: two bitwise-identical windows planted far
-        apart — the bf16 sweep must pair them EXACTLY (value 1.0);
-      * compiled path: `ops.compiled_lowering_smoke` AOT-lowers BOTH
-        kernel entries with interpret=False for TPU on this CPU host via
-        jax.export — lowering seconds + Mosaic module sizes must be
-        nonzero (rows emit 0 with a note on jax builds without the export
-        API; the gate runs on the pinned-latest leg where it exists)."""
+        apart — the bf16 sweep must pair them EXACTLY (value 1.0)."""
     from repro.core.matrix_profile import matrix_profile
     from repro.core.precision import as_precision, profile_tolerance
     from repro.core.zstats import x64_scope
@@ -665,25 +647,6 @@ def bench_precision():
     emit("mp_bf16_planted_exact", exact,
          f"planted pair ({a_pos},{b_pos}) recovered exactly "
          f"(gate==1; value is a flag, not us)")
-    # compiled path: AOT Mosaic lowering of both kernel entries
-    try:
-        info = ops.compiled_lowering_smoke()
-        emit("mp_kernel_compiled_lower_n4096", info["lower_s"] * 1e6,
-             f"jax.export TPU AOT, interpret=False; "
-             f"mosaic={int(info['mosaic'])} (gate: nonzero)")
-        emit("mp_kernel_compiled_self_module_bytes",
-             float(info["self_module_bytes"]),
-             "StableHLO module size, self-join entry (gate: nonzero)")
-        emit("mp_kernel_compiled_ab_module_bytes",
-             float(info["ab_module_bytes"]),
-             "StableHLO module size, AB-join entry (gate: nonzero)")
-    except RuntimeError as e:
-        emit("mp_kernel_compiled_lower_n4096", 0.0,
-             f"export-api-unavailable({e})")
-        emit("mp_kernel_compiled_self_module_bytes", 0.0,
-             "export-api-unavailable")
-        emit("mp_kernel_compiled_ab_module_bytes", 0.0,
-             "export-api-unavailable")
 
 
 def bench_lm_train():
@@ -786,11 +749,14 @@ BENCHES = {
 
 def main(argv: list[str] | None = None) -> None:
     """Run all benches, or a subset: python benchmarks/run.py ab_join batch"""
+    from repro.launch.cache import enable_compile_cache
+
     names = list(argv if argv is not None else sys.argv[1:]) or list(BENCHES)
     unknown = [n for n in names if n not in BENCHES]
     if unknown:
         raise SystemExit(f"unknown benches {unknown}; choose from "
                          f"{sorted(BENCHES)}")
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for n in names:
         BENCHES[n]()

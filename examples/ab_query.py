@@ -73,10 +73,10 @@ def main():
           f"B-side profile for free from the one-pass engine")
     assert abs(best_r - 3000) <= 3
 
-    # same join through the Pallas kernel wrapper (interpret mode on CPU)
+    # same join through the Pallas kernel wrapper (the interpreter on CPU)
     kres = ops.natsa_ab_join(query, ref, m, it=256, dt=16)
     err = np.abs(np.asarray(kres.p) - dist)
-    print(f"[pallas kernel, interpret] max |Δ| vs engine: "
+    print(f"[pallas kernel] max |Δ| vs engine: "
           f"{err[np.isfinite(err)].max():.2e}")
 
     # 2. streaming corpus + query scoring

@@ -57,7 +57,7 @@ def main():
     kres = ops.natsa_matrix_profile(ts, m, it=256, dt=16)
     err = np.abs(np.asarray(kres.p) - np.asarray(result.p))
     err = err[np.isfinite(err)]
-    print(f"[pallas kernel, interpret] max |Δ| vs engine: {err.max():.2e}")
+    print(f"[pallas kernel] max |Δ| vs engine: {err.max():.2e}")
 
     kmot = analytics.top_motifs(kres, max_motifs=1)[0]
     print(f"[pallas kernel] top motif pair: ({kmot.a}, {kmot.b})")

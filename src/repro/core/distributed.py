@@ -34,10 +34,9 @@ from jax.sharding import PartitionSpec as P
 from repro.core.matrix_profile import (
     ColState, DEFAULT_RESEED, NEG, ProfileState, TopKState,
     _ab_padded_streams, ab_reseed, ab_row_tile, band_rowmax, band_rowmax_ab,
-    band_topk, band_topk_ab, centered_windows,
+    band_topk, band_topk_ab, centered_windows, reseed_table,
 )
 from repro.core.zstats import CrossStats, ZStats
-from repro.utils.compat import shard_map_compat
 
 
 def pmax_profile(state: ProfileState, axis: str) -> ProfileState:
@@ -87,13 +86,15 @@ def worker_chunk(stats: ZStats, k0: jax.Array, k1: jax.Array,
     accum for the distributed backend, so the pmap'd bodies stay a single
     compiled specialization per geometry."""
     l = stats.n_subsequences
-    wc = centered_windows(stats) if reseed_every is not None else None
+    table = (reseed_table(centered_windows(stats), reseed_every, band)
+             if reseed_every is not None else None)
 
     def body(b, carry):
         state, col = carry
         start = k0 + b * band
         rc, ri, win, wi = band_rowmax(stats, start, band,
-                                      reseed_every=reseed_every, windows_c=wc)
+                                      reseed_every=reseed_every,
+                                      seed_table=table)
         live = start < k1            # trailing band may overhang the chunk
         rc = jnp.where(live, rc, NEG)
         win = jnp.where(live, win, NEG)
@@ -155,13 +156,15 @@ def worker_chunk_topk(stats: ZStats, k0: jax.Array, k1: jax.Array,
     """`worker_chunk` widened to exact top-k: the merged (l, k) best-first
     set of every row AND column update the chunk's cells imply."""
     l = stats.n_subsequences
-    wc = centered_windows(stats) if reseed_every is not None else None
+    table = (reseed_table(centered_windows(stats), reseed_every, band)
+             if reseed_every is not None else None)
 
     def body(b, carry):
         rows, col = carry
         start = k0 + b * band
         rc, ri, win, wi = band_topk(stats, start, band, k,
-                                    reseed_every=reseed_every, windows_c=wc)
+                                    reseed_every=reseed_every,
+                                    seed_table=table)
         live = start < k1            # trailing band may overhang the chunk
         rc = jnp.where(live, rc, NEG)
         win = jnp.where(live, win, NEG)
@@ -241,10 +244,10 @@ def make_round_fn(plan, mesh, axis: str = "workers"):
                              reseed)
         return pmax_profile(running.merge(local), axis)
 
-    shmapped = shard_map_compat(
+    shmapped = jax.shard_map(
         per_worker, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis)),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )
     return jax.jit(shmapped)
 
@@ -275,9 +278,9 @@ def make_round_fn_ab(plan, mesh, axis: str = "workers"):
         return (pmax_profile(running_a.merge(loc_a), axis),
                 pmax_profile(running_b.merge(loc_b), axis))
 
-    shmapped = shard_map_compat(
+    shmapped = jax.shard_map(
         per_worker, mesh=mesh,
         in_specs=(P(), P(), P(), P(axis), P(axis)),
-        out_specs=(P(), P()),
+        out_specs=(P(), P()), check_vma=False,
     )
     return jax.jit(shmapped)

@@ -154,12 +154,46 @@ def default_exclusion(window: int) -> int:
     return max(1, -(-int(window) // 4))
 
 
+def _skew(x: jax.Array, start, rows: int, cols: int) -> jax.Array:
+    """(rows, cols) matrix M[d, t] = x[start + d + t] (zero past the end of
+    `x`): one dynamic slice and a static pad/reshape skew — no gather, which
+    a TPU executes one scalar at a time."""
+    n = rows + cols
+    win = jax.lax.dynamic_slice(jnp.pad(x, (0, n)), (start,), (n,))
+    # `rows` copies of the window read at row length n + 1: row d starts d
+    # elements further into its copy
+    flat = jnp.pad(jnp.tile(win, rows), (0, rows))
+    return flat.reshape(rows, n + 1)[:, :cols]
+
+
 def centered_windows(stats: ZStats) -> jax.Array:
     """(l, m) matrix of centered subsequences — used only for reseeding."""
     m = stats.window
     l = stats.n_subsequences
-    idx = jnp.arange(l)[:, None] + jnp.arange(m)[None, :]
-    return stats.ts[idx] - stats.mu[:, None]
+    return _skew(stats.ts, 0, m, l).T - stats.mu[:, None]
+
+
+def reseed_table(windows_c: jax.Array, reseed_every: int,
+                 band: int) -> jax.Array:
+    """Exact reseed dots of every segment-start row r_s = s*R, laid out so
+    one band's seeds are one slice: `table[s, k] = <Wc[r_s], Wc[r_s + k]>`
+    for r_s + k < l (other entries only ever seed masked cells). ONE
+    (S, m) x (m, l) matmul per sweep at full f32 precision (a TPU's default
+    f32 matmul rounds through bf16), instead of re-gathering (band, S, m)
+    windows per band. Columns cover k < l + band, every start a band of
+    width `band` can take."""
+    l = windows_c.shape[0]
+    R = int(reseed_every)
+    rows = windows_c[::R]                                       # (S, m)
+    s = rows.shape[0]
+    exact = jnp.dot(rows, windows_c.T,
+                    precision=jax.lax.Precision.HIGHEST)        # (S, l)
+    # read row s at row length L + R, so it starts s*R further in: the
+    # flat index s*(L + R) + k is exact[s, s*R + k] while s*R + k < L
+    width = l + band
+    flat = jnp.pad(jnp.pad(exact, ((0, 0), (0, band))).reshape(-1),
+                   (0, s * R))
+    return flat.reshape(s, width + R)
 
 
 def _row_harvest(tile: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -317,35 +351,38 @@ jax.tree_util.register_dataclass(BankedColState,
 
 def _band_corr(stats: ZStats, k0, band: int,
                reseed_every: int | None = None,
-               windows_c: jax.Array | None = None,
+               seed_table: jax.Array | None = None,
                accum_dtype=jnp.float32) -> jax.Array:
     """The (D, l) correlation tile of the diagonal band [k0, k0+band) —
     the shared substrate of `band_rowmax` (k = 1 harvest) and `band_topk`
     (top-k harvest). Invalid cells (j >= l) are masked to NEG.
 
     `reseed_every=R` bounds f32 drift of the cumulative-sum recurrence: the
-    covariance is recomputed EXACTLY (direct centered dot via `windows_c`)
-    every R rows and the running sum corrected per segment — the TPU analogue
-    of NATSA PUs re-seeding their diagonal registers per work unit. SCAMP
+    covariance is recomputed EXACTLY (direct centered dot, read from
+    `seed_table` = `reseed_table(...)`, built here when not passed) every R
+    rows and the running sum corrected per segment — the TPU analogue of
+    NATSA PUs re-seeding their diagonal registers per work unit. SCAMP
     solves the same drift with fp64, which the TPU VPU does not have.
+    The j-side streams of the band are skewed slices (`_skew`), not
+    gathers.
     """
     l = stats.n_subsequences
     acc = jnp.dtype(accum_dtype)
     ks = k0 + jnp.arange(band)                     # (D,)
     i = jnp.arange(l)                              # (l,)
     j = i[None, :] + ks[:, None]                   # (D, l)
-    jc = jnp.minimum(j, l - 1)                     # clamp for gathers
     valid = j < l
 
     # streams arrive in the plan's (possibly reduced) stream dtype; every
     # product/cumsum below runs in the accum dtype (no-op upcast when both
-    # are f32 — the default path is bitwise-unchanged)
+    # are f32 — the default path is bitwise-unchanged). Cells past the end
+    # (j >= l) read zeros and are masked below.
     dfa = stats.df.astype(acc)
     dga = stats.dg.astype(acc)
     invna = stats.invn.astype(acc)
-    dfj = jnp.take(dfa, jc)
-    dgj = jnp.take(dga, jc)
-    invnj = jnp.take(invna, jc)
+    dfj = _skew(dfa, k0, band, l)
+    dgj = _skew(dga, k0, band, l)
+    invnj = _skew(invna, k0, band, l)
     cov0b = jnp.take(stats.cov0.astype(acc), jnp.minimum(ks, l - 1))
 
     delta = dfa[None, :] * dgj + dfj * dga[None, :]
@@ -353,19 +390,16 @@ def _band_corr(stats: ZStats, k0, band: int,
     cov = cov0b[:, None] + jnp.cumsum(delta, axis=1)
 
     if reseed_every is not None:
-        if windows_c is None:
-            windows_c = centered_windows(stats)
         R = int(reseed_every)
-        n_seg = -(-l // R)
-        rows = jnp.minimum(jnp.arange(n_seg) * R, l - 1)          # (S,)
-        # exact cov at segment-start rows: <Wc[r], Wc[r+k]>
-        jr = jnp.minimum(rows[None, :] + ks[:, None], l - 1)      # (D, S)
-        w_r = windows_c[rows]                                     # (S, m)
-        w_j = windows_c[jr]                                       # (D, S, m)
-        seeds = jnp.einsum("sm,dsm->ds", w_r, w_j)                # (D, S)
-        drift = seeds - jnp.take(cov, rows, axis=1)               # (D, S)
-        seg = jnp.minimum(i // R, n_seg - 1)                      # (l,)
-        cov = cov + jnp.take(drift, seg, axis=1)
+        if seed_table is None:
+            seed_table = reseed_table(centered_windows(stats).astype(acc), R,
+                                      band)
+        n_seg = seed_table.shape[0]
+        # exact cov at segment-start rows r = s*R: <Wc[r], Wc[r+k]>
+        seeds = jax.lax.dynamic_slice(seed_table, (0, k0),
+                                      (n_seg, band)).T            # (D, S)
+        drift = seeds - cov[:, ::R]                               # (D, S)
+        cov = cov + jnp.repeat(drift, R, axis=1)[:, :l]
 
     corr = cov * invna[None, :] * invnj
     # invn < 0 is the missing-data sentinel (zstats): pairs touching a
@@ -378,7 +412,7 @@ def _band_corr(stats: ZStats, k0, band: int,
 
 def band_rowmax(stats: ZStats, k0, band: int, *,
                 reseed_every: int | None = None,
-                windows_c: jax.Array | None = None,
+                seed_table: jax.Array | None = None,
                 accum_dtype=jnp.float32
                 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Two-sided harvest of the diagonal band [k0, k0+band).
@@ -392,7 +426,7 @@ def band_rowmax(stats: ZStats, k0, band: int, *,
     traced (dynamic), `band` is static. Diagonals >= l contribute nothing.
     """
     l = stats.n_subsequences
-    corr = _band_corr(stats, k0, band, reseed_every, windows_c, accum_dtype)
+    corr = _band_corr(stats, k0, band, reseed_every, seed_table, accum_dtype)
     i = jnp.arange(l)
     corr_best, d_win = _row_harvest(corr)
     idx_best = (i + k0 + d_win).astype(jnp.int32)
@@ -427,7 +461,7 @@ def _topk_col_window(corr: jax.Array, k: int,
 
 def band_topk(stats: ZStats, k0, band: int, k: int, *,
               reseed_every: int | None = None,
-              windows_c: jax.Array | None = None,
+              seed_table: jax.Array | None = None,
               accum_dtype=jnp.float32
               ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """`band_rowmax` widened to exact top-k: (row (l, k), row_idx, win
@@ -435,7 +469,7 @@ def band_topk(stats: ZStats, k0, band: int, k: int, *,
     position's candidates live on distinct diagonals, so the per-tile top-k
     is exact and the cross-band `TopKState` union stays exact."""
     l = stats.n_subsequences
-    corr = _band_corr(stats, k0, band, reseed_every, windows_c, accum_dtype)
+    corr = _band_corr(stats, k0, band, reseed_every, seed_table, accum_dtype)
     vals, d = _topk_rows(corr, k)
     idx = (jnp.arange(l)[:, None] + k0 + d).astype(jnp.int32)
     idx = jnp.where(vals > NEG, idx, -1)
@@ -468,9 +502,9 @@ def chunk_rowmax_split(stats: ZStats, k0, k1_static: int, band: int,
     acc = jnp.dtype(accum_dtype)
     n_bands = -(-k1_static // band)
     # reseed seeds accumulate m-term dots: upcast the (possibly reduced)
-    # centered windows to the accum dtype before the einsum
-    wc = (centered_windows(stats).astype(acc)
-          if reseed_every is not None else None)
+    # centered windows to the accum dtype before the matmul
+    table = (reseed_table(centered_windows(stats).astype(acc), reseed_every,
+                          band) if reseed_every is not None else None)
     # self-join diagonals are non-negative: no left pad; the right pad
     # absorbs the last window (start <= l-1) and overshooting all-fill bands
     pad_r = l + band
@@ -479,8 +513,8 @@ def chunk_rowmax_split(stats: ZStats, k0, k1_static: int, band: int,
         state, col = carry
         start = k0 + b * band
         rc, ri, win, wi = band_rowmax(stats, start, band,
-                                      reseed_every=reseed_every, windows_c=wc,
-                                      accum_dtype=acc)
+                                      reseed_every=reseed_every,
+                                      seed_table=table, accum_dtype=acc)
         state = state.merge(ProfileState(rc, ri))
         col = col.merge_window(win, wi, start)
         return (state, col), None
@@ -509,15 +543,15 @@ def chunk_topk(stats: ZStats, k0, k1_static: int, band: int, k: int,
     l = stats.n_subsequences
     acc = jnp.dtype(accum_dtype)
     n_bands = -(-k1_static // band)
-    wc = (centered_windows(stats).astype(acc)
-          if reseed_every is not None else None)
+    table = (reseed_table(centered_windows(stats).astype(acc), reseed_every,
+                          band) if reseed_every is not None else None)
 
     def body(carry, b):
         rows, col = carry
         start = k0 + b * band
         rc, ri, win, wi = band_topk(stats, start, band, k,
-                                    reseed_every=reseed_every, windows_c=wc,
-                                    accum_dtype=acc)
+                                    reseed_every=reseed_every,
+                                    seed_table=table, accum_dtype=acc)
         rows = rows.merge(TopKState(rc, ri))
         col = col.merge_window(win, wi, start)
         return (rows, col), None
@@ -897,7 +931,8 @@ def _band_corr_ab(cross: CrossStats, k0, band: int, *,
         jr = jnp.clip(jrow, 0, lb - 1)
         w_r = wa[rows_c].astype(acc)                              # (S, m)
         w_j = wb[jr].astype(acc)                                  # (D, S, m)
-        seeds = jnp.einsum("sm,dsm->ds", w_r, w_j)                # (D, S)
+        seeds = jnp.einsum("sm,dsm->ds", w_r, w_j,
+                           precision=jax.lax.Precision.HIGHEST)   # (D, S)
         drift = seeds - jnp.take(cov, rows_rel, axis=1)           # (D, S)
         # segments whose start cell is outside the rectangle keep the raw
         # cumsum (bounded by R rows of drift, same as the baseline bound)
@@ -1230,7 +1265,8 @@ def ab_join_rowstream(cross: CrossStats, exclusion: int = 0,
         wb = centered_windows(sb).astype(acc)
         import numpy as np
         rows = np.arange(0, la, int(R))                # static row ids
-        exact = jnp.einsum("sm,lm->sl", wa[rows], wb)  # (S, l_b) reseed rows
+        exact = jnp.einsum("sm,lm->sl", wa[rows], wb,  # (S, l_b) reseed rows
+                           precision=jax.lax.Precision.HIGHEST)
     jj = jnp.arange(lb)
     neg = jnp.asarray(NEG, acc)
 
@@ -1298,7 +1334,8 @@ def ab_join_rowstream_topk(cross: CrossStats, exclusion: int = 0,
         wb = centered_windows(sb).astype(acc)
         import numpy as np
         rows = np.arange(0, la, int(R))                # static row ids
-        exact = jnp.einsum("sm,lm->sl", wa[rows], wb)  # (S, l_b) reseed rows
+        exact = jnp.einsum("sm,lm->sl", wa[rows], wb,  # (S, l_b) reseed rows
+                           precision=jax.lax.Precision.HIGHEST)
     jj = jnp.arange(lb)
     neg = jnp.asarray(NEG, acc)
 

@@ -59,7 +59,7 @@ from repro.core.precision import DEFAULT_PRECISION, PrecisionSpec, as_precision
 from repro.core.result import HarvestSpec
 from repro.core.zstats import CrossStats, ZStats, corr_to_dist
 # tile-geometry defaults only — repro.kernels itself imports nothing
-from repro.kernels import DEFAULT_DT, DEFAULT_IT
+from repro.kernels import DEFAULT_DT, DEFAULT_IT, resolve_interpret
 
 BACKENDS = ("engine", "rowstream", "kernel", "distributed")
 
@@ -101,7 +101,7 @@ class SweepPlan:
     reseed_every: int | None = DEFAULT_RESEED
     # -- backend -----------------------------------------------------------
     backend: str = "engine"         # engine | rowstream | kernel | distributed
-    interpret: bool = True          # kernel backend: Pallas interpret mode
+    interpret: bool = False         # kernel backend: Pallas interpret mode
     batch: int | None = None        # vmapped stack size (engine/rowstream)
     # -- precision ---------------------------------------------------------
     # stream/accum/seed dtypes, decided HERE at plan time (default: the
@@ -182,7 +182,7 @@ def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
                col_tile: int | None = None,
                reseed_every: int | None = DEFAULT_RESEED,
                it: int = DEFAULT_IT, dt: int = DEFAULT_DT,
-               interpret: bool = True,
+               interpret: bool | None = None,
                batch: int | None = None,
                precision: PrecisionSpec | str | None = None) -> SweepPlan:
     """Heuristic planner: fill in every sweep decision an entry point used to
@@ -207,6 +207,10 @@ def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
         axis — so k must fit min(l_a, l_b) resp. `band`;
       * the nonnorm recurrence has no top-k harvest (nobody asked for
         amplitude-anomaly k-NN yet) — explicit ValueError.
+
+    `interpret` is resolved here, once: `None` runs the Pallas interpreter
+    only on the CPU backend (`kernels.resolve_interpret`), so the plan
+    always carries a concrete bool.
 
     `precision` is a `PrecisionSpec`, a preset name ("bf16"/"f16"/"f64"),
     or None (the bitwise-default f32 spec). Precision rules pinned here:
@@ -318,7 +322,8 @@ def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
                      normalize=normalize, harvest=spec, swap_ab=swap_ab,
                      band=int(band), clamp_rows=clamp_rows, col_tile=col_tile,
                      it=int(it), dt=int(dt), reseed_every=reseed_every,
-                     backend=backend, interpret=interpret, batch=batch,
+                     backend=backend, interpret=resolve_interpret(interpret),
+                     batch=batch,
                      precision=prec)
 
 

@@ -368,19 +368,21 @@ def compute_stats_host(ts, window: int, out_dtype=None, seed_dtype=None,
 # fleet's jitted/vmapped update alike. Two deliberate choices keep it
 # shape-independent and replayable:
 #
-#   * every dot product is an elementwise multiply + `sum` over the window
-#     axis (NO matmul: BLAS/XLA gemm tilings round differently per shape, a
-#     (1, m) fleet row would not match a (p, m) bulk-append block);
+#   * every dot product is an elementwise multiply + a left-to-right fold
+#     over the window axis (`_fold_sum`). NO matmul: BLAS/XLA gemm tilings
+#     round differently per shape, a (1, m) fleet row would not match a
+#     (p, m) bulk-append block. No `jnp.sum` either: a reduce's grouping is
+#     the compiler's choice, and on a TPU it changes with the array's shape
+#     (a TPU v5e fleet and its replay stopped agreeing bitwise);
 #   * BOTH surfaces call the kernels under jit (`sqdist_block_jit` for the
-#     per-series path, the fleet's own jitted update for the other): XLA's
-#     fused mul->reduce emits FMAs, so jitted output differs from eager
-#     per-primitive dispatch in the last ulp (measured) — but the fused
-#     lowering is shape- and context-independent (measured: full-block vs
-#     single-row vs batched vs carry-materialized inputs all agree
-#     bitwise), so two jitted callers agree where eager-vs-jit would not.
-#     Each FP intermediate additionally carries a `lax.optimization_barrier`
-#     pin to keep surrounding graphs from restructuring the kernel's
-#     producer chains (exact ops — where/clip/max/compare — need none);
+#     per-series path, the fleet's own jitted update for the other), and
+#     each FP intermediate carries a `_pin` fence so no product is
+#     contracted into the add that consumes it (exact ops —
+#     where/clip/max/compare — need none). Without the fence XLA's CPU
+#     backend contracts mul->add into FMAs in some loop shapes and not in
+#     others (jax 0.9: a one-row block rounded differently from a
+#     many-row one), and the fleet round and the single-point append no
+#     longer agree;
 #   * f64 throughout — callers outside jit wrap calls in `x64_scope()`.
 #
 # Degenerate-window conventions mirror the historical `StreamingProfile`
@@ -396,27 +398,39 @@ def x64_scope():
     (the repo's engines are f32 and the global flag stays off; the
     incremental surfaces opt in per call — jit traces/calls made inside the
     scope are cached under it, so fleet state stays f64 end to end)."""
-    from jax.experimental import enable_x64
-
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def _pin(x: jax.Array) -> jax.Array:
-    """`lax.optimization_barrier` on one array — the fusion fence that
-    keeps jitted kernel arithmetic bitwise-equal to eager dispatch (see
-    the section comment)."""
-    return jax.lax.optimization_barrier(x)
+    """Round `x` where it stands: the fence that keeps a product from being
+    contracted into the add that consumes it (an FMA rounds once where the
+    unfused pair rounds twice). `lax.optimization_barrier` no longer does
+    this — XLA's CPU pipeline expands it before fusion, and LLVM then
+    contracts mul->add in some loop shapes and not in others. A select on
+    `x == x` is an identity (NaN in, NaN out) that neither XLA nor LLVM
+    folds, and it sits between the two operations."""
+    return jnp.where(x == x, x, jnp.nan)
+
+
+def _fold_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis, strictly left to right with every partial
+    sum pinned: the same rounding at every array shape (see the section
+    comment)."""
+    acc = x[..., 0]
+    for k in range(1, x.shape[-1]):
+        acc = _pin(acc + x[..., k])
+    return acc
 
 
 def centered_block(w: jax.Array) -> tuple[jax.Array, jax.Array]:
     """(..., q, m) raw windows -> (centered windows, centered norms).
     Mean is spelled sum-then-divide so each rounding step is its own
     pinned primitive."""
-    s = _pin(jnp.sum(w, axis=-1, keepdims=True))
+    s = _fold_sum(w)[..., None]
     mu = _pin(s / w.shape[-1])
     c = _pin(w - mu)
     sq = _pin(c * c)
-    ss = _pin(jnp.sum(sq, axis=-1))
+    ss = _fold_sum(sq)
     return c, _pin(jnp.sqrt(ss))
 
 
@@ -435,7 +449,7 @@ def sqdist_znorm_from_parts(ac, an, bc, bn, *, window: int) -> jax.Array:
     -> (..., p, q). Split out so the fleet can keep B-side centered windows
     resident and still share the A-side arithmetic bitwise."""
     prod = _pin(ac[..., :, None, :] * bc[..., None, :, :])
-    cross = _pin(jnp.sum(prod, axis=-1))
+    cross = _fold_sum(prod)
     nn = _pin(an[..., :, None] * bn[..., None, :])
     denom = jnp.maximum(nn, 1e-300)
     ratio = _pin(cross / denom)
@@ -449,7 +463,7 @@ def window_sumsq(w: jax.Array) -> jax.Array:
     """(..., q, m) raw windows -> (..., q) sum of squares, pinned — the
     non-normalized path's precomputable part."""
     sq = _pin(w * w)
-    return _pin(jnp.sum(sq, axis=-1))
+    return _fold_sum(sq)
 
 
 def sqdist_nonnorm_from_parts(wa, sa, wb, sb) -> jax.Array:
@@ -457,7 +471,7 @@ def sqdist_nonnorm_from_parts(wa, sa, wb, sb) -> jax.Array:
     precomputed squared norms (`sa = sum(wa^2)`, `sb = sum(wb^2)`):
     ||a - b||^2 by expansion, no (p, q, m) gemm."""
     prod = _pin(wa[..., :, None, :] * wb[..., None, :, :])
-    cross = _pin(jnp.sum(prod, axis=-1))
+    cross = _fold_sum(prod)
     ssum = _pin(sa[..., :, None] + sb[..., None, :])
     c2 = _pin(2.0 * cross)
     return _pin(ssum - c2)

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -67,8 +69,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("bq", "bk", "causal", "interpret"))
 def flash_attention(q, k, v, *, bq: int = 128, bk: int = 128,
-                    causal: bool = True, interpret: bool = True):
-    """q/k/v: (B, H, S, D) -> (B, H, S, D). S divisible by bq and bk."""
+                    causal: bool = True, interpret: bool | None = None):
+    """q/k/v: (B, H, S, D) -> (B, H, S, D). S divisible by bq and bk.
+    `interpret=None` runs the Pallas interpreter on the CPU backend only."""
     b, h, s, d = q.shape
     assert s % bq == 0 and s % bk == 0, (s, bq, bk)
     scale = 1.0 / (d ** 0.5)
@@ -94,7 +97,7 @@ def flash_attention(q, k, v, *, bq: int = 128, bk: int = 128,
             pltpu.VMEM((bq, 1), jnp.float32),     # running denom
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, h, s, d)
 

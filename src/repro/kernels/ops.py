@@ -13,20 +13,20 @@ Pipeline (mirrors the paper's Fig. 1 dataflow):
 The old pipeline ran a second reversed-series launch for the column half —
 twice the streamed bytes, twice the stats precompute, same answer.
 
-`interpret=True` (default) runs the kernel body on CPU for validation; on a
-real TPU pass interpret=False.
+`interpret=None` (default) runs the kernel body in the Pallas interpreter on
+the CPU backend and the compiled Mosaic kernel on a TPU
+(`kernels.resolve_interpret`).
 """
 
 from __future__ import annotations
 
-import functools
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
 from repro.core.zstats import (
-    CrossStats, ZStats, compute_cross_stats_host, compute_stats_host,
+    CrossStats, ZStats, compute_stats_host,
 )
 from repro.kernels import DEFAULT_DT, DEFAULT_IT, natsa_mp
 
@@ -77,7 +77,8 @@ def auto_col_tile(col_len: int, it: int, dt: int,
 
 def rowmax_from_stats(stats: ZStats, *, excl: int, it: int = DEFAULT_IT,
                       dt: int = DEFAULT_DT,
-                      col_tile: int | None = None, interpret: bool = True):
+                      col_tile: int | None = None,
+                      interpret: bool | None = None):
     """Two-sided self-join harvest via ONE kernel launch.
 
     Returns (corr (l,), idx, col_corr (l,), col_idx): the row-max half
@@ -102,8 +103,9 @@ def _merge_corr(corr_a, idx_a, corr_b, idx_b):
 
 def natsa_matrix_profile(ts, window: int, *, exclusion: int | None = None,
                          it: int = DEFAULT_IT, dt: int = DEFAULT_DT,
-                         col_tile: int | None = None, interpret: bool = True,
-                         k: int = 1, harvest: str = "merged",
+                         col_tile: int | None = None,
+                         interpret: bool | None = None, k: int = 1,
+                         harvest: str = "merged",
                          precision=None):
     """Full matrix profile via the Pallas kernel -> `ProfileResult` (the
     left/right split — the kernel's column/row halves — finishes lazily
@@ -168,7 +170,8 @@ def _pad_streams_ab(cross: CrossStats, it: int, dt: int, s0: int, s1: int):
 
 def ab_rowmax_from_stats(cross: CrossStats, *, exclusion: int = 0,
                          it: int = DEFAULT_IT, dt: int = DEFAULT_DT,
-                         col_tile: int | None = None, interpret: bool = True):
+                         col_tile: int | None = None,
+                      interpret: bool | None = None):
     """Two-sided AB harvest via the kernel.
 
     With exclusion == 0 the whole signed space [-(l_a-1), l_b) is ONE kernel
@@ -210,7 +213,7 @@ def ab_rowmax_from_stats(cross: CrossStats, *, exclusion: int = 0,
 def natsa_ab_join(ts_a, ts_b, window: int, *, exclusion: int | None = None,
                   it: int = DEFAULT_IT, dt: int = DEFAULT_DT,
                   col_tile: int | None = None,
-                  interpret: bool = True, return_b: bool = False,
+                  interpret: bool | None = None, return_b: bool = False,
                   k: int = 1, precision=None):
     """AB join via the Pallas kernel -> `ProfileResult`.
 
@@ -325,94 +328,3 @@ def kernel_roofline(l: int, excl: int, it: int, dt: int, *,
         "vmem_bytes": kernel_vmem_bytes(l, it, dt),
         "resident": kernel_vmem_bytes(l, it, dt) <= VMEM_BYTES,
     }
-
-
-# -- compiled (interpret=False) lowering --------------------------------------
-
-
-def aot_export_tpu(fn, *args):
-    """AOT-lower a jitted callable for TPU on ANY host — the compiled-path
-    smoke. `jax.jit(...).lower()` on a CPU-only host stops at "Only
-    interpret mode is supported on CPU backend" before Mosaic ever runs;
-    `jax.export` instead drives the FULL TPU lowering pipeline (Pallas ->
-    Mosaic -> StableHLO custom calls) cross-platform, so CI proves
-    `interpret=False` compiles without owning a TPU.
-
-    Returns the `Exported` artifact; `.mlir_module()` is the lowered module
-    (the CI gate asserts it is non-trivial and carries the Mosaic kernel).
-    Raises RuntimeError when this jax build has no export API — callers
-    skip gracefully (the 0.4.34 CI leg predates the stable module).
-    """
-    jitted = jax.jit(fn)
-    try:
-        from jax import export as _export
-        return _export.export(jitted, platforms=["tpu"])(*args)
-    except (ImportError, AttributeError, TypeError):
-        pass
-    try:
-        from jax.experimental import export as _exp
-        try:
-            return _exp.export(jitted, lowering_platforms=("tpu",))(*args)
-        except TypeError:
-            return _exp.export(jitted, platforms=["tpu"])(*args)
-    except ImportError as e:
-        raise RuntimeError(
-            "no jax.export API in this jax build; compiled-path smoke "
-            "requires jax >= 0.4.30") from e
-
-
-def compiled_lowering_smoke(n: int = 4096, window: int = 128, *,
-                            it: int = DEFAULT_IT,
-                            dt: int = DEFAULT_DT) -> dict:
-    """Prove both kernel entries LOWER with interpret=False, end to end.
-
-    Builds real stats for an (n,) self-join and an (n, n//2) AB join, then
-    AOT-exports the exact jitted kernel cores a compiled run would execute.
-    Returns {"self_module_bytes", "ab_module_bytes", "mosaic"} — all
-    nonzero/true on success (the CI compiled-smoke job gates on this).
-    Raises RuntimeError when the jax build cannot export (caller skips)."""
-    import time
-
-    from repro.core import plan as plan_mod
-
-    rng = np.random.default_rng(7)
-    ts = np.cumsum(rng.standard_normal(n))
-    m = int(window)
-    out = {}
-    t0 = time.perf_counter()
-
-    plan = plan_mod.plan_sweep(m, n - m + 1, backend="kernel", it=it, dt=dt,
-                               interpret=False)
-    stats = compute_stats_host(ts, m)
-    df, dg, invn, cov0p, n_rows, n_diags, l = _pad_streams(
-        stats, it, dt, plan.exclusion)
-    ct = auto_col_tile(n_rows * it + plan.exclusion + n_diags * dt, it, dt,
-                       plan.col_tile)
-    fn = functools.partial(natsa_mp.rowmax_profile, it=it, dt=dt,
-                           excl=plan.exclusion, l=l, col_tile=ct,
-                           interpret=False)
-    exp = aot_export_tpu(fn, df, dg, invn, cov0p)
-    mod = exp.mlir_module()
-    out["self_module_bytes"] = len(mod)
-    out["mosaic"] = ("tpu_custom_call" in mod) or ("mosaic" in mod)
-
-    ts_b = np.cumsum(rng.standard_normal(n // 2))
-    cross = compute_cross_stats_host(ts, ts_b, m)
-    (df_i, dg_i, invn_i, df_j, dg_j, invn_j, cov0p,
-     n_rows, n_diags, jpad) = _pad_streams_ab(
-        cross, it, dt, -(cross.l_a - 1), cross.l_b)
-    ct = auto_col_tile(
-        max(n_rows * it - (cross.l_a - 1) + n_diags * dt + jpad,
-            cross.l_b + jpad), it, dt, None)
-    fn_ab = functools.partial(
-        natsa_mp.rowmax_profile_ab, it=it, dt=dt,
-        k_start=-(cross.l_a - 1), k_end=cross.l_b, l_i=cross.l_a,
-        l_j=cross.l_b, jpad=jpad, col_tile=ct, interpret=False)
-    exp_ab = aot_export_tpu(fn_ab, df_i, dg_i, invn_i, df_j, dg_j, invn_j,
-                            cov0p)
-    mod_ab = exp_ab.mlir_module()
-    out["ab_module_bytes"] = len(mod_ab)
-    out["mosaic"] = out["mosaic"] and (("tpu_custom_call" in mod_ab)
-                                       or ("mosaic" in mod_ab))
-    out["lower_s"] = time.perf_counter() - t0
-    return out

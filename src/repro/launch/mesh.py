@@ -6,39 +6,25 @@ from __future__ import annotations
 import jax
 
 
-def compat_mesh(shape, axes, devices=None):
-    """Mesh construction across jax versions: `axis_types` appeared after
-    0.4.x (older releases have neither the kwarg nor jax.sharding.AxisType;
-    Auto is their only behaviour anyway), and `jax.make_mesh` itself only
-    exists from 0.4.35 — before that, build jax.sharding.Mesh directly."""
-    make = getattr(jax, "make_mesh", None)
-    if make is None:
-        import math
-
-        import numpy as np
-
-        devs = list(devices) if devices is not None else jax.devices()
-        n = math.prod(shape)
-        return jax.sharding.Mesh(np.asarray(devs[:n]).reshape(shape), axes)
-    kwargs = {} if devices is None else dict(devices=devices)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    return make(shape, axes, **kwargs)
+def _auto_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with every axis in Auto sharding mode (the default
+    became Explicit; the sweeps and the LM stack are written for Auto)."""
+    return jax.make_mesh(shape, axes, (jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_worker_mesh(n: int | None = None, axis: str = "workers"):
     """1-D mesh over available devices for the matrix-profile engine."""
     devs = jax.devices()
     n = len(devs) if n is None else n
-    return compat_mesh((n,), (axis,), devices=devs[:n])
+    return _auto_mesh((n,), (axis,), devices=devs[:n])
 
 
 def dp_axes(mesh) -> tuple[str, ...]:

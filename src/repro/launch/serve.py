@@ -55,6 +55,8 @@ def run_service(n_series: int, n: int, window: int, n_queries: int,
         "qps": n_queries / t_serve,
         "answers": answers,
         "stats": svc.stats,
+        "series": series,
+        "queries": queries,
     }
 
 
@@ -76,6 +78,9 @@ def main(argv=None):
                     help="skip device sharding even when devices > 1")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     rep = run_service(args.series, args.n, args.window, args.queries,
                       args.query_n, args.k, seed=args.seed,
                       use_mesh=not args.no_mesh)

@@ -225,10 +225,8 @@ def moe_ffn(cfg, p: Tree, x, ctx: ShardCtx | None):
         return _moe_local(cfg, p_, x_, ctx, tp_axis=mlp_axis, ep_axis=ep_axis,
                           batch_axes=bt)
 
-    from repro.utils.compat import shard_map_compat
-
-    return shard_map_compat(
+    return jax.shard_map(
         inner, mesh=ctx.mesh,
         in_specs=(pspecs, xspec),
-        out_specs=(xspec, P()),
+        out_specs=(xspec, P()), check_vma=False,
     )(p, x)

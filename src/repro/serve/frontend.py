@@ -8,9 +8,10 @@
   2. per corpus group (shard x reference length) it stacks the Q x S
      (query, series) pairs into one vmapped engine sweep (padded to a
      power-of-two batch so jit compiles O(log) variants, not one per batch
-     size) and dispatches it through the async `RoundLoop` — host assembly
-     of the next group overlaps device execution of the previous one, and
-     `block_until_ready` happens only at delivery;
+     size; split by query when the pairs' window matrices would pass
+     `DISPATCH_BYTES`) and dispatches it through the async `RoundLoop` —
+     host assembly of the next group overlaps device execution of the
+     previous one, and `block_until_ready` happens only at delivery;
   3. at delivery it union-merges the per-shard neighbor sets with
      `TopKState.merge` (`lax.top_k` over negated distances with indices
      packed as `sid * stride + position`) — exact for the union because
@@ -35,6 +36,10 @@ import numpy as np
 
 from repro.serve.queue import AdmissionQueue, PendingQuery
 from repro.serve.rounds import RoundLoop
+
+# Device bytes one dispatch may spend on the batched sweep's window
+# matrices; a batch whose (query, series) pairs need more is split by query.
+DISPATCH_BYTES = 1 << 31
 
 
 @dataclasses.dataclass
@@ -150,17 +155,26 @@ class ProfileService:
                 if group.shard not in rec["failed_shards"]:
                     rec["failed_shards"].append(group.shard)
                 continue
-            npairs = len(batch) * len(group.sids)
-            pad = 1 << (npairs - 1).bit_length()      # power-of-two bucket
-            plan = self.corpus.plan_for(group, lq, k=k, batch=pad)
-            stats = self.corpus.assemble_batch(group, parts, plan)
-            res = plan_mod.execute(plan, stats)       # async dispatch
-            if k > 1:
-                payload = {"d": res.topk_dist, "i": res.topk_index}
-            else:
-                payload = {"d": res.dist, "i": res.index}
-            rec["expected"] += 1
-            self._loop.dispatch(payload, meta=(rec, group))
+            # queries per dispatch: as many as keep the sweep's window
+            # matrices inside DISPATCH_BYTES (at least one). Each pair's
+            # vmapped rowstream builds both sides' (l, m) f32 centered
+            # windows for its exact reseed dots.
+            ns = len(group.sids)
+            per_query = ns * 4 * (lq + group.l_ref) * m
+            step = max(1, min(len(batch), DISPATCH_BYTES // per_query))
+            for q0 in range(0, len(batch), step):
+                q1 = min(q0 + step, len(batch))
+                npairs = (q1 - q0) * ns
+                pad = 1 << (npairs - 1).bit_length()  # power-of-two bucket
+                plan = self.corpus.plan_for(group, lq, k=k, batch=pad)
+                stats = self.corpus.assemble_batch(group, parts[q0:q1], plan)
+                res = plan_mod.execute(plan, stats)   # async dispatch
+                if k > 1:
+                    payload = {"d": res.topk_dist, "i": res.topk_index}
+                else:
+                    payload = {"d": res.dist, "i": res.index}
+                rec["expected"] += 1
+                self._loop.dispatch(payload, meta=(rec, group, q0, q1))
         if rec["expected"] == 0:
             self._finalize(rec)                       # every shard failed
 
@@ -181,8 +195,8 @@ class ProfileService:
         return True
 
     def _on_delivered(self, meta, payload) -> None:
-        rec, group = meta
-        rec["collected"].append((group, payload))
+        rec, group, q0, q1 = meta
+        rec["collected"].append((group, q0, q1, payload))
         if len(rec["collected"]) == rec["expected"]:
             self._finalize(rec)
 
@@ -197,24 +211,28 @@ class ProfileService:
         state = TopKState(corr=jnp.full((nq, lq, k), -jnp.inf, jnp.float32),
                           index=jnp.full((nq, lq, k), -1, jnp.int32))
         covered = 0
-        for group, payload in rec["collected"]:
-            ns = len(group.sids)
-            covered += ns
-            d = jnp.asarray(payload["d"])[:nq * ns]
-            i = jnp.asarray(payload["i"])[:nq * ns]
+        for group, q0, q1, payload in rec["collected"]:
+            ns, nb = len(group.sids), q1 - q0
+            if q0 == 0:                   # count each group's series once
+                covered += ns
+            d = jnp.asarray(payload["d"])[:nb * ns]
+            i = jnp.asarray(payload["i"])[:nb * ns]
             if k == 1:
                 d, i = d[..., None], i[..., None]
             # rows are query-major: (q * S + s) -> (Q, S, lq, k); pack the
             # neighbor as a single id so the union is one top_k
-            d = jnp.moveaxis(d.reshape(nq, ns, lq, k), 1, 2)
-            i = jnp.moveaxis(i.reshape(nq, ns, lq, k), 1, 2)
+            d = jnp.moveaxis(d.reshape(nb, ns, lq, k), 1, 2)
+            i = jnp.moveaxis(i.reshape(nb, ns, lq, k), 1, 2)
             sid = jnp.asarray(group.sids, jnp.int32)[None, None, :, None]
             packed = jnp.where(i >= 0, sid * stride + i, -1)
-            cand = TopKState(corr=(-d).reshape(nq, lq, ns * k),
-                             index=packed.reshape(nq, lq, ns * k))
+            cand = TopKState(corr=(-d).reshape(nb, lq, ns * k),
+                             index=packed.reshape(nb, lq, ns * k))
             # exact union: shards hold disjoint series, so no neighbor is
             # offered twice (allreduce_topk's argument, applied to shards)
-            state = state.merge(cand)
+            part = TopKState(corr=state.corr[q0:q1],
+                             index=state.index[q0:q1]).merge(cand)
+            state = TopKState(corr=state.corr.at[q0:q1].set(part.corr),
+                              index=state.index.at[q0:q1].set(part.index))
         dist = np.asarray(-state.corr)
         packed = np.asarray(state.index)
         pos = np.where(packed >= 0, packed % stride, -1).astype(np.int32)

@@ -30,9 +30,8 @@ sys.path.insert(0, %r)
 import numpy as np, jax
 from repro.core.scheduler import AnytimeScheduler
 from repro.core.faults import FaultInjector, FaultPolicy, flip_bits
-from repro.launch.mesh import compat_mesh
 
-mesh = compat_mesh((8,), ("workers",))
+mesh = jax.make_mesh((8,), ("workers",), (jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(3)
 ts = np.cumsum(rng.normal(size=700)).astype(np.float32)
 m = 24

@@ -117,8 +117,8 @@ def test_ckpt_format1_files_still_restore(tmp_path):
 @pytest.fixture(scope="module")
 def sched_mod():
     from repro.core.scheduler import AnytimeScheduler
-    from repro.launch.mesh import compat_mesh
-    mesh = compat_mesh((1,), ("workers",))
+    import jax
+    mesh = jax.make_mesh((1,), ("workers",), (jax.sharding.AxisType.Auto,))
     ts = np.cumsum(np.random.default_rng(5).normal(size=240))
     mk = lambda **kw: AnytimeScheduler(ts, 12, mesh, chunks_per_worker=4,
                                        band=16, **kw)
@@ -175,12 +175,12 @@ def test_scheduler_resume_corruption_without_fallback_raises(sched_mod,
 def test_scheduler_resume_geometry_mismatch_is_valueerror(sched_mod,
                                                           tmp_path):
     from repro.core.scheduler import AnytimeScheduler
-    from repro.launch.mesh import compat_mesh
+    import jax
     path = str(tmp_path / "ck.npz")
     s = sched_mod()
     s.run(1)
     s.checkpoint(path)
-    mesh = compat_mesh((1,), ("workers",))
+    mesh = jax.make_mesh((1,), ("workers",), (jax.sharding.AxisType.Auto,))
     other = AnytimeScheduler(np.cumsum(np.ones(300)), 12, mesh)
     with pytest.raises(ValueError, match="geometry mismatch"):
         other.resume(path)

@@ -19,8 +19,7 @@ import numpy as np, jax, jax.numpy as jnp
 from repro.core.scheduler import AnytimeScheduler
 from repro.core.ref import matrix_profile_bruteforce
 
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((8,), ("workers",))
+mesh = jax.make_mesh((8,), ("workers",), (jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(1)
 ts = np.cumsum(rng.normal(size=600)).astype(np.float32)
 m = 20

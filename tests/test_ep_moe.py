@@ -23,8 +23,7 @@ from repro.models.common import init_params, sanitized_pspecs
 from repro.models.moe import ShardCtx
 
 cfg = configs.get_smoke("olmoe-1b-7b")   # 8 experts top-2, d=64
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"), (jax.sharding.AxisType.Auto,) * 2)
 spec = moe.moe_spec(cfg)
 params = init_params(jax.random.key(0), spec)
 x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model), jnp.float32)

@@ -162,11 +162,11 @@ def test_kernel_interp_matches_oracle():
 
 def test_scheduler_matches_oracle():
     from repro.core.scheduler import AnytimeScheduler
-    from repro.launch.mesh import compat_mesh
+    import jax
     t = _series(300, 9, GAPS)
     m, excl = 16, 4
     P, I, bad = _oracle_self(t, m, excl)
-    mesh = compat_mesh((1,), ("workers",))
+    mesh = jax.make_mesh((1,), ("workers",), (jax.sharding.AxisType.Auto,))
     sch = AnytimeScheduler(t, m, mesh, exclusion=excl, chunks_per_worker=4,
                            band=16)
     sch.run()

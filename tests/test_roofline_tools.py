@@ -67,8 +67,7 @@ def test_roofline_terms_bottleneck():
 
 
 def test_sanitize_pspec_rules():
-    from repro.launch.mesh import compat_mesh
-    mesh = compat_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), (jax.sharding.AxisType.Auto,))
 
     class FakeMesh:
         shape = {"model": 16, "data": 4}

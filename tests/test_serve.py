@@ -50,10 +50,17 @@ def _reference_union(q, series, m):
     return best_d, best_s, best_i
 
 
-def test_batched_service_matches_sequential_engine_bitwise():
+@pytest.mark.parametrize("dispatch_bytes", [None, 1])
+def test_batched_service_matches_sequential_engine_bitwise(monkeypatch,
+                                                           dispatch_bytes):
     """The headline equality: a batch of concurrent queries answered by the
     service is BITWISE-equal (distances, winning series, positions) to
-    looping per-(query, series) sweeps and reducing on the host."""
+    looping per-(query, series) sweeps and reducing on the host — also when
+    the memory bound splits the batch into one dispatch per query."""
+    from repro.serve import frontend
+
+    if dispatch_bytes is not None:
+        monkeypatch.setattr(frontend, "DISPATCH_BYTES", dispatch_bytes)
     rng = np.random.default_rng(0)
     series = _corpus_series(rng)
     corpus = ShardedCorpus(series, WINDOW, n_shards=2)

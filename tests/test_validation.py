@@ -101,8 +101,8 @@ def test_streaming_profile_validates_construction_and_append():
 
 def test_scheduler_validates_inputs():
     from repro.core.scheduler import AnytimeScheduler
-    from repro.launch.mesh import compat_mesh
-    mesh = compat_mesh((1,), ("workers",))
+    import jax
+    mesh = jax.make_mesh((1,), ("workers",), (jax.sharding.AxisType.Auto,))
     with pytest.raises(ValueError, match="1-D"):
         AnytimeScheduler(np.zeros((4, 4)), 8, mesh)
     with pytest.raises(ValueError, match="window must be >= 2"):
