@@ -1,0 +1,9 @@
+"""Share of the traced join window in which no operation ran on the device."""
+
+UNIT = "share"
+
+
+def read(record, trace):
+    if record["kind"] != "join" or trace is None or trace.window_s <= 0:
+        return None
+    return 1.0 - trace.busy_s / trace.window_s
