@@ -228,15 +228,10 @@ def bench_long_series():
     Mixed precision rides the same series: the bf16-stream engine row
     (`precision="bf16"` routes the normalized self-join through the
     dot-product tile sweep) is timed INTERLEAVED with the f32 row so the
-    CI-gated >=1.5x ratio is an honest same-loop A/B, and both kernel
-    rows convert to `mp_kernel_roofline_fraction_*` — achieved fraction
-    of the modeled HBM bandwidth roofline (nonzero/finite is the gate;
-    CPU-host interpret wall clock is far below 1.0 by construction)."""
+    CI-gated >=1.5x ratio is an honest same-loop A/B."""
     from repro.core.matrix_profile import matrix_profile
     from repro.core.ref import matrix_profile_bruteforce
-    from repro.launch import roofline
     n, m = 16384, 128
-    excl = m // 4
     ts = pipeline.random_walk(n, seed=21)
     t_bf = _timeit(lambda t: matrix_profile_bruteforce(jnp.asarray(t), m)[0],
                    ts, reps=1)
@@ -273,14 +268,6 @@ def bench_long_series():
          f"speedup_vs_bf={t_bf/t_krn:.2f}x(banked col_tile=4096)")
     emit(f"mp_kernel_interp_bf16_n{n}", t_krn16,
          f"vs_f32_kernel={t_krn/t_krn16:.2f}x(interpret-mode, ungated)")
-    l = n - m + 1
-    frac = roofline.roofline_fraction(l, excl, t_krn / 1e6, it=2048, dt=64)
-    frac16 = roofline.roofline_fraction(l, excl, t_krn16 / 1e6, it=2048,
-                                        dt=64, stream_bytes=2)
-    emit(f"mp_kernel_roofline_fraction_n{n}", frac,
-         "achieved/HBM-roofline (model units; gate: nonzero, not us)")
-    emit(f"mp_kernel_roofline_fraction_bf16_n{n}", frac16,
-         "bf16 streams halve the modeled traffic (gate: nonzero, not us)")
 
 
 def bench_batch():

@@ -41,6 +41,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 # NOTE: names are imported from the module directly — `repro.core`'s package
 # namespace rebinds `matrix_profile` to the FUNCTION of the same name, so a
 # `from repro.core import matrix_profile` would grab the entry point, not
@@ -437,15 +439,20 @@ def execute(plan: SweepPlan, stats) -> SweepResult:
     a raw series array (self, nonnorm), or an `(ts_a, ts_b)` tuple (AB,
     nonnorm). Batched plans take the same payloads with a leading stack axis
     (`jax.tree.map(jnp.stack, ...)`). Distributed plans run round-by-round —
-    build their SPMD step with `round_executor` instead."""
+    build their SPMD step with `round_executor` instead.
+
+    The `mp.sweep` span covers the call; each path's harvest after the
+    launch is its child `mp.finish`, so `mp.sweep`'s self time is the
+    host's launch of the sweep."""
     _check_stats(plan, stats)
     if plan.backend == "distributed":
         raise ValueError("distributed plans execute round-by-round: build "
                          "the SPMD round fn with round_executor(plan, mesh) "
                          "— AnytimeScheduler drives it")
-    if plan.kind == "self":
-        return _execute_self(plan, stats)
-    return _execute_ab(plan, stats)
+    with obs.span("mp.sweep"):
+        if plan.kind == "self":
+            return _execute_self(plan, stats)
+        return _execute_ab(plan, stats)
 
 
 # public lazy-field name -> SweepResult field, for eagerly materializing a
@@ -481,8 +488,6 @@ def _execute_self(plan: SweepPlan, stats) -> SweepResult:
         split = nonnorm_profile_from_ts(
             jnp.asarray(stats, plan.precision.stream_dtype), m,
             plan.exclusion, plan.band, accum_dtype=plan.precision.accum)
-        res = SweepResult(nonnorm_to_distance(split.merged),
-                          split.merged.index)
 
         def fin_split():
             return dict(left_p=nonnorm_to_distance(split.left),
@@ -490,7 +495,10 @@ def _execute_self(plan: SweepPlan, stats) -> SweepResult:
                         right_p=nonnorm_to_distance(split.right),
                         right_i=split.right.index)
 
-        return _attach(res, ("split",), fin_split, eager_split)
+        with obs.span("mp.finish"):
+            res = SweepResult(nonnorm_to_distance(split.merged),
+                              split.merged.index)
+            return _attach(res, ("split",), fin_split, eager_split)
     if plan.backend == "kernel":
         from repro.kernels import ops
 
@@ -499,14 +507,15 @@ def _execute_self(plan: SweepPlan, stats) -> SweepResult:
         corr_r, idx_r, corr_c, idx_c = ops.rowmax_from_stats(
             stats, excl=plan.exclusion, it=plan.it, dt=plan.dt,
             col_tile=plan.col_tile, interpret=plan.interpret)
-        corr, idx = ops._merge_corr(corr_r, idx_r, corr_c, idx_c)
-        res = SweepResult(_kernel_dist(corr, m), idx)
 
         def fin_split():
             return dict(left_p=_kernel_dist(corr_c, m), left_i=idx_c,
                         right_p=_kernel_dist(corr_r, m), right_i=idx_r)
 
-        return _attach(res, ("split",), fin_split, eager_split)
+        with obs.span("mp.finish"):
+            corr, idx = ops._merge_corr(corr_r, idx_r, corr_c, idx_c)
+            res = SweepResult(_kernel_dist(corr, m), idx)
+            return _attach(res, ("split",), fin_split, eager_split)
     if plan.harvest.k > 1:
         fn = lambda s: profile_topk_from_stats(             # noqa: E731
             s, plan.exclusion, plan.band, plan.reseed_every, plan.harvest.k,
@@ -514,11 +523,6 @@ def _execute_self(plan: SweepPlan, stats) -> SweepResult:
         if plan.batch is not None:
             fn = jax.vmap(fn)
         merged, rows, col = fn(stats)
-        # dist IS slot 0 of the top-k conversion, so the top-k fields ride
-        # along at zero extra cost — only the split stays deferred
-        dk = merged.to_distance(m)
-        res = SweepResult(dk[..., 0], merged.index[..., 0],
-                          topk_dist=dk, topk_index=merged.index)
 
         def fin_split():
             return dict(left_p=col.to_distance(m)[..., 0],
@@ -526,7 +530,13 @@ def _execute_self(plan: SweepPlan, stats) -> SweepResult:
                         right_p=rows.to_distance(m)[..., 0],
                         right_i=rows.index[..., 0])
 
-        return _attach(res, ("split",), fin_split, eager_split)
+        with obs.span("mp.finish"):
+            # dist IS slot 0 of the top-k conversion, so the top-k fields
+            # ride along at zero extra cost — only the split stays deferred
+            dk = merged.to_distance(m)
+            res = SweepResult(dk[..., 0], merged.index[..., 0],
+                              topk_dist=dk, topk_index=merged.index)
+            return _attach(res, ("split",), fin_split, eager_split)
     if plan.precision.reduced_stream:
         # recurrence-free dot-product tile sweep: the ONLY self-join engine
         # path for 16-bit streams (bounded absolute corr error, no drift,
@@ -541,7 +551,6 @@ def _execute_self(plan: SweepPlan, stats) -> SweepResult:
     if plan.batch is not None:
         fn = jax.vmap(fn)
     split = fn(stats)
-    res = SweepResult(split.merged.to_distance(m), split.merged.index)
 
     def fin_split():
         return dict(left_p=split.left.to_distance(m),
@@ -549,7 +558,9 @@ def _execute_self(plan: SweepPlan, stats) -> SweepResult:
                     right_p=split.right.to_distance(m),
                     right_i=split.right.index)
 
-    return _attach(res, ("split",), fin_split, eager_split)
+    with obs.span("mp.finish"):
+        res = SweepResult(split.merged.to_distance(m), split.merged.index)
+        return _attach(res, ("split",), fin_split, eager_split)
 
 
 def _execute_ab(plan: SweepPlan, stats) -> SweepResult:
@@ -576,13 +587,14 @@ def _execute_ab(plan: SweepPlan, stats) -> SweepResult:
         sa, sb = fn(stats)
         if plan.swap_ab:
             sa, sb = sb, sa
-        res = SweepResult(sa.to_distance(m), sa.index)
 
         def fin_b():
             # B's state IS rowstream's running accumulator — computed anyway
             return dict(b_p=sb.to_distance(m), b_i=sb.index)
 
-        return _attach(res, ("b",), fin_b, two_sided)
+        with obs.span("mp.finish"):
+            res = SweepResult(sa.to_distance(m), sa.index)
+            return _attach(res, ("b",), fin_b, two_sided)
     if plan.backend == "kernel":
         from repro.kernels import ops
 
@@ -591,13 +603,14 @@ def _execute_ab(plan: SweepPlan, stats) -> SweepResult:
             col_tile=plan.col_tile, interpret=plan.interpret)
         if plan.swap_ab:
             corr, idx, corr_b, idx_b = corr_b, idx_b, corr, idx
-        res = SweepResult(_kernel_dist(corr, m), idx)
 
         def fin_b():
             # the kernel launch always harvests both halves
             return dict(b_p=_kernel_dist(corr_b, m), b_i=idx_b)
 
-        return _attach(res, ("b",), fin_b, two_sided)
+        with obs.span("mp.finish"):
+            res = SweepResult(_kernel_dist(corr, m), idx)
+            return _attach(res, ("b",), fin_b, two_sided)
     # band-diagonal engine: row clamp makes orientation moot, never swapped;
     # a minimal plan really skips the column accumulators (the entry-layer
     # saving), so its B side has no raw finish — lazy access re-executes
@@ -608,9 +621,10 @@ def _execute_ab(plan: SweepPlan, stats) -> SweepResult:
     if plan.batch is not None:
         fn = jax.vmap(fn)
     sa, sb = fn(stats)
-    return SweepResult(sa.to_distance(m), sa.index,
-                       sb.to_distance(m) if two_sided else None,
-                       sb.index if two_sided else None)
+    with obs.span("mp.finish"):
+        return SweepResult(sa.to_distance(m), sa.index,
+                           sb.to_distance(m) if two_sided else None,
+                           sb.index if two_sided else None)
 
 
 def _execute_ab_topk(plan: SweepPlan, stats, two_sided: bool) -> SweepResult:
@@ -636,17 +650,18 @@ def _execute_ab_topk(plan: SweepPlan, stats, two_sided: bool) -> SweepResult:
         if plan.batch is not None:
             fn = jax.vmap(fn)
         ta, tb = fn(stats)
-    da = ta.to_distance(m)
-    res = SweepResult(da[..., 0], ta.index[..., 0],
-                      topk_dist=da, topk_index=ta.index)
-    if tb is not None:
-        def fin_b():
-            db = tb.to_distance(m)        # one conversion serves both groups
-            return dict(b_p=db[..., 0], b_i=tb.index[..., 0],
-                        b_topk_p=db, b_topk_i=tb.index)
+    with obs.span("mp.finish"):
+        da = ta.to_distance(m)
+        res = SweepResult(da[..., 0], ta.index[..., 0],
+                          topk_dist=da, topk_index=ta.index)
+        if tb is not None:
+            def fin_b():
+                db = tb.to_distance(m)    # one conversion serves both groups
+                return dict(b_p=db[..., 0], b_i=tb.index[..., 0],
+                            b_topk_p=db, b_topk_i=tb.index)
 
-        return _attach(res, ("b", "b_topk"), fin_b, two_sided)
-    return res
+            return _attach(res, ("b", "b_topk"), fin_b, two_sided)
+        return res
 
 
 def round_executor(plan: SweepPlan, mesh, axis: str = "workers"):

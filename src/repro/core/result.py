@@ -42,6 +42,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+from repro import obs
+
 
 @dataclasses.dataclass(frozen=True)
 class HarvestSpec:
@@ -128,14 +130,15 @@ class _LazyHarvest:
         group = _LAZY_GROUPS[name]
         if not self._producible(result, group):
             return
-        fn = self.raw.get(group)
-        if fn is not None:
-            fields = fn()
-        else:
-            fields = self._recompute()
-        for key, val in fields.items():
-            if object.__getattribute__(result, "_" + key) is None:
-                object.__setattr__(result, "_" + key, val)
+        with obs.span("mp.finish"):
+            fn = self.raw.get(group)
+            if fn is not None:
+                fields = fn()
+            else:
+                fields = self._recompute()
+            for key, val in fields.items():
+                if object.__getattribute__(result, "_" + key) is None:
+                    object.__setattr__(result, "_" + key, val)
 
     def _recompute(self) -> dict:
         if self.stats is None:
@@ -297,14 +300,15 @@ def build_result(plan, res, stats=None) -> ProfileResult:
     the recompute fallback — zero-cost raw finishes still work).
     """
     spec = plan.harvest
-    lazy = _LazyHarvest(plan, stats, raw=getattr(res, "raw", None))
-    return ProfileResult(
-        p=res.dist, i=res.index,
-        left_p=res.left_dist, left_i=res.left_index,
-        right_p=res.right_dist, right_i=res.right_index,
-        b_p=res.dist_b, b_i=res.index_b,
-        topk_p=res.topk_dist, topk_i=res.topk_index,
-        b_topk_p=res.topk_dist_b, b_topk_i=res.topk_index_b,
-        kind=plan.kind, window=plan.window, exclusion=plan.exclusion,
-        normalize=plan.normalize, k=spec.k, backend=plan.backend,
-        lazy=lazy)
+    with obs.span("mp.finish"):
+        lazy = _LazyHarvest(plan, stats, raw=getattr(res, "raw", None))
+        return ProfileResult(
+            p=res.dist, i=res.index,
+            left_p=res.left_dist, left_i=res.left_index,
+            right_p=res.right_dist, right_i=res.right_index,
+            b_p=res.dist_b, b_i=res.index_b,
+            topk_p=res.topk_dist, topk_i=res.topk_index,
+            b_topk_p=res.topk_dist_b, b_topk_i=res.topk_index_b,
+            kind=plan.kind, window=plan.window, exclusion=plan.exclusion,
+            normalize=plan.normalize, k=spec.k, backend=plan.backend,
+            lazy=lazy)

@@ -45,6 +45,8 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 
+from repro import obs
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -133,17 +135,28 @@ def cross_stats_from_parts(stats_a: ZStats, wa, stats_b: ZStats, wb,
     `seed_dot` role); it defaults to `out_dtype`. The dots themselves are
     always computed in f64 and rounded exactly once at the end.
     """
+    with obs.span("mp.seeds"):
+        seeds = seed_dots(wa, wb)
+    if seed_dtype is None:
+        seed_dtype = out_dtype
+    dt = jnp.float32 if seed_dtype is None else seed_dtype
+    with obs.span("mp.put"):
+        cov0s = jnp.asarray(seeds, dt)
+        obs.count("mp.put_bytes", cov0s.nbytes)
+    return CrossStats(a=stats_a, b=stats_b, cov0s=cov0s)
+
+
+def seed_dots(wa, wb):
+    """The f64 seed of every diagonal of the (l_a, l_b) rectangle, indexed
+    k + l_a - 1, from the two sides' f64 centered-window matrices: exact
+    window dots at each diagonal's first cell."""
     import numpy as np
 
     wa = np.asarray(wa, np.float64)
     wb = np.asarray(wb, np.float64)
     neg = wa[1:] @ wb[0]            # k = -1 .. -(l_a-1), start cells (-k, 0)
     pos = wb @ wa[0]                # k = 0 .. l_b-1,     start cells (0, k)
-    if seed_dtype is None:
-        seed_dtype = out_dtype
-    dt = jnp.float32 if seed_dtype is None else seed_dtype
-    cov0s = jnp.asarray(np.concatenate([neg[::-1], pos]), dt)
-    return CrossStats(a=stats_a, b=stats_b, cov0s=cov0s)
+    return np.concatenate([neg[::-1], pos])
 
 
 def compute_cross_stats_host(ts_a, ts_b, window: int, out_dtype=None,
@@ -161,16 +174,21 @@ def compute_cross_stats_host(ts_a, ts_b, window: int, out_dtype=None,
     corpus, long stream vs small reference set).
     """
     m = int(window)
-    sa, wa = compute_stats_host(ts_a, m, out_dtype=out_dtype,
-                                seed_dtype=seed_dtype,
-                                min_subsequences=1,
-                                return_centered_windows=True)
-    sb, wb = compute_stats_host(ts_b, m, out_dtype=out_dtype,
-                                seed_dtype=seed_dtype,
-                                min_subsequences=1,
-                                return_centered_windows=True)
-    return cross_stats_from_parts(sa, wa, sb, wb, out_dtype=out_dtype,
-                                  seed_dtype=seed_dtype)
+    with obs.span("mp.stats"):
+        sa, wa = compute_stats_host(ts_a, m, out_dtype=out_dtype,
+                                    seed_dtype=seed_dtype,
+                                    min_subsequences=1,
+                                    return_centered_windows=True)
+        sb, wb = compute_stats_host(ts_b, m, out_dtype=out_dtype,
+                                    seed_dtype=seed_dtype,
+                                    min_subsequences=1,
+                                    return_centered_windows=True)
+        cross = cross_stats_from_parts(sa, wa, sb, wb, out_dtype=out_dtype,
+                                       seed_dtype=seed_dtype)
+        # free the window matrices (GBs for long series) inside the span:
+        # releasing them is part of the prep's cost
+        del wa, wb
+    return cross
 
 
 def moving_mean_var(ts: jax.Array, m: int) -> tuple[jax.Array, jax.Array]:
@@ -300,60 +318,70 @@ def compute_stats_host(ts, window: int, out_dtype=None, seed_dtype=None,
     """
     import numpy as np
 
-    t = np.asarray(ts, np.float64)
-    if t.ndim != 1:
-        raise ValueError(f"time series must be 1-D, got shape {t.shape}")
-    m = int(window)
-    n = t.shape[0]
-    min_n = 2 * m if min_subsequences is None else m + int(min_subsequences) - 1
-    if n < min_n:
-        raise ValueError(f"series too short: n={n} < {min_n} "
-                         f"(window={m}, min_subsequences={min_subsequences})")
-    finite = np.isfinite(t)
-    masked = None
-    if not finite.all():
-        # fill gaps with the finite mean so every downstream cumsum/dot is
-        # finite; windows touching a gap are flagged and get the invn = -1
-        # sentinel below (their other stream values are don't-cares — every
-        # engine masks their cells before any harvest)
-        fill = t[finite].mean() if finite.any() else 0.0
-        t = np.where(finite, t, fill)
-        nbad = np.concatenate([[0], np.cumsum(~finite)])
-        masked = (nbad[m:] - nbad[:-m]) > 0            # (l,) touches a gap
-    t = t - t.mean()                      # shift-invariant; improves f32 casts
-    l = n - m + 1
-    csum = np.concatenate([[0.0], np.cumsum(t)])
-    mu = (csum[m:] - csum[:-m]) / m
-    # zero-copy window view instead of an (l, m) index-gather: stats prep is
-    # on the timed serving path, so the only O(l*m) materialization is the
-    # centered matrix itself
-    view = np.lib.stride_tricks.sliding_window_view(t, m)
-    w = view - mu[:, None]                # exact two-pass centering
-    norm = np.sqrt(np.einsum("lm,lm->l", w, w))
-    # flat-window guard must be RELATIVE: cumsum roundoff in mu leaves
-    # ~1e-15-relative residues in w for constant windows, and an exact
-    # norm > 0 test would then emit invn ~ 1e15 instead of the corr-0
-    # convention. Windows whose deviation is below 1e-8 of their magnitude
-    # are z-norm-degenerate either way. scale^2 = sum(t[idx]^2) is
-    # norm^2 + m*mu^2 (sum of deviations is ~0), so no second window pass.
-    scale2 = norm * norm + m * mu * mu
-    flat = norm * norm <= 1e-16 * np.maximum(scale2, 1e-300)
-    invn = np.where(~flat & (norm > 0), 1.0 / np.maximum(norm, 1e-300), 0.0)
-    if masked is not None:
-        invn = np.where(masked, -1.0, invn)   # missing-data sentinel
-    tail, head = t[m:], t[: l - 1]
-    df = np.concatenate([[0.0], (tail[: l - 1] - head) / 2.0])
-    dg = np.concatenate([[0.0], (tail[: l - 1] - mu[1:]) + (head - mu[:-1])])
-    cov0 = w @ w[0]
-    dt = jnp.float32 if out_dtype is None else out_dtype
-    sdt = dt if seed_dtype is None else seed_dtype
-    # single rounding f64 -> target dtype (never through an f32 staging cast)
-    f = lambda x, d=dt: jnp.asarray(np.asarray(x, np.float64), d)
-    stats = ZStats(ts=f(t), mu=f(mu), invn=f(invn), df=f(df), dg=f(dg),
-                   cov0=f(cov0, sdt), window=m)
-    if return_centered_windows:
-        return stats, w
-    return stats
+    with obs.span("mp.stats"):
+        t = np.asarray(ts, np.float64)
+        if t.ndim != 1:
+            raise ValueError(f"time series must be 1-D, got shape {t.shape}")
+        m = int(window)
+        n = t.shape[0]
+        min_n = 2 * m if min_subsequences is None else m + int(min_subsequences) - 1
+        if n < min_n:
+            raise ValueError(f"series too short: n={n} < {min_n} "
+                             f"(window={m}, min_subsequences={min_subsequences})")
+        finite = np.isfinite(t)
+        masked = None
+        if not finite.all():
+            # fill gaps with the finite mean so every downstream cumsum/dot is
+            # finite; windows touching a gap are flagged and get the invn = -1
+            # sentinel below (their other stream values are don't-cares — every
+            # engine masks their cells before any harvest)
+            fill = t[finite].mean() if finite.any() else 0.0
+            t = np.where(finite, t, fill)
+            nbad = np.concatenate([[0], np.cumsum(~finite)])
+            masked = (nbad[m:] - nbad[:-m]) > 0            # (l,) touches a gap
+        t = t - t.mean()                      # shift-invariant; improves f32 casts
+        l = n - m + 1
+        csum = np.concatenate([[0.0], np.cumsum(t)])
+        mu = (csum[m:] - csum[:-m]) / m
+        # zero-copy window view instead of an (l, m) index-gather: stats prep is
+        # on the timed serving path, so the only O(l*m) materialization is the
+        # centered matrix itself
+        view = np.lib.stride_tricks.sliding_window_view(t, m)
+        w = view - mu[:, None]                # exact two-pass centering
+        obs.count("mp.window_bytes", w.nbytes)
+        norm = np.sqrt(np.einsum("lm,lm->l", w, w))
+        # flat-window guard must be RELATIVE: cumsum roundoff in mu leaves
+        # ~1e-15-relative residues in w for constant windows, and an exact
+        # norm > 0 test would then emit invn ~ 1e15 instead of the corr-0
+        # convention. Windows whose deviation is below 1e-8 of their magnitude
+        # are z-norm-degenerate either way. scale^2 = sum(t[idx]^2) is
+        # norm^2 + m*mu^2 (sum of deviations is ~0), so no second window pass.
+        scale2 = norm * norm + m * mu * mu
+        flat = norm * norm <= 1e-16 * np.maximum(scale2, 1e-300)
+        invn = np.where(~flat & (norm > 0), 1.0 / np.maximum(norm, 1e-300), 0.0)
+        if masked is not None:
+            invn = np.where(masked, -1.0, invn)   # missing-data sentinel
+        tail, head = t[m:], t[: l - 1]
+        df = np.concatenate([[0.0], (tail[: l - 1] - head) / 2.0])
+        dg = np.concatenate([[0.0], (tail[: l - 1] - mu[1:]) + (head - mu[:-1])])
+        with obs.span("mp.seeds"):
+            cov0 = w @ w[0]
+        if not return_centered_windows:
+            # free the matrix (4.3 GB at n = 2^19, m = 1024) inside the span
+            # and before the streams' copies are enqueued
+            del w
+        dt = jnp.float32 if out_dtype is None else out_dtype
+        sdt = dt if seed_dtype is None else seed_dtype
+        # single rounding f64 -> target dtype (never through an f32 staging cast)
+        f = lambda x, d=dt: jnp.asarray(np.asarray(x, np.float64), d)
+        with obs.span("mp.put"):
+            stats = ZStats(ts=f(t), mu=f(mu), invn=f(invn), df=f(df), dg=f(dg),
+                           cov0=f(cov0, sdt), window=m)
+            obs.count("mp.put_bytes",
+                      sum(x.nbytes for x in jax.tree.leaves(stats)))
+        if return_centered_windows:
+            return stats, w
+        return stats
 
 
 # -- shared streaming/fleet block distances -----------------------------------

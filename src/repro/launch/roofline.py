@@ -207,22 +207,3 @@ def matrix_profile_roofline(l: int, excl: int, it: int | None = None,
                          model_flops_total=flops,
                          n_chips=n_chips)
 
-
-def roofline_fraction(l: int, excl: int, elapsed_s: float,
-                      it: int | None = None, dt: int | None = None,
-                      stream_bytes: int = 4) -> float:
-    """Achieved fraction of the HBM bandwidth roofline for one measured
-    sweep: (analytic HBM bytes / `HBM_BW`) / elapsed wall seconds.
-
-    1.0 means the sweep ran exactly at the memory roofline of the modeled
-    chip; CPU-host interpret/compiled runs land far below it, but the row
-    must be NONZERO and finite — that is the CI gate: the analytic model,
-    the tile geometry, and the timer all agree on units. Reduced streams
-    (`stream_bytes=2`) lower the numerator, which is the point: the same
-    elapsed time earns a SMALLER fraction because less traffic was needed.
-    """
-    if elapsed_s <= 0.0:
-        raise ValueError(f"elapsed_s must be positive, got {elapsed_s}")
-    terms = matrix_profile_roofline(l, excl, it=it, dt=dt,
-                                    stream_bytes=stream_bytes)
-    return terms.t_memory / float(elapsed_s)

@@ -7,7 +7,8 @@ Loads `--series` synthetic reference series ONCE into a `ShardedCorpus`
 (z-stats + centered windows resident, shards device-placed across the
 worker mesh when more than one device is visible), then pushes `--queries`
 concurrent AB-join queries through the batched `ProfileService` front-end
-and reports throughput. Run with
+and reports throughput, the queue's stats and the program's counters
+(`repro.obs`) over the timed load. Run with
 `XLA_FLAGS=--xla_force_host_platform_device_count=N` to shard across N
 host devices.
 """
@@ -26,6 +27,7 @@ def run_service(n_series: int, n: int, window: int, n_queries: int,
     """Build corpus + service, answer the query load, return a report."""
     import jax
 
+    from repro import obs
     from repro.launch.mesh import make_worker_mesh
     from repro.serve import ProfileService, ShardedCorpus
 
@@ -44,9 +46,12 @@ def run_service(n_series: int, n: int, window: int, n_queries: int,
     queries = [rng.normal(size=query_n) for _ in range(n_queries)]
     svc.serve(queries[:1], k=k)               # warm the compiled variants
 
+    before = obs.counters()
     t0 = time.monotonic()
     answers = svc.serve(queries, k=k)
     t_serve = time.monotonic() - t0
+    counters = {name: v - before.get(name, 0)
+                for name, v in obs.counters().items()}
     return {
         "mesh_devices": 1 if mesh is None else mesh.devices.size,
         "shards": corpus.n_shards,
@@ -55,6 +60,7 @@ def run_service(n_series: int, n: int, window: int, n_queries: int,
         "qps": n_queries / t_serve,
         "answers": answers,
         "stats": svc.stats,
+        "counters": counters,
         "series": series,
         "queries": queries,
     }
@@ -94,6 +100,7 @@ def main(argv=None):
           f" best d={float(np.min(a.result.p)):.4f} "
           f"(series {int(a.series[int(np.argmin(a.result.p))])})")
     print(f"[serve] queue: {rep['stats']}")
+    print(f"[serve] host work of the timed load: {rep['counters']}")
     return rep
 
 

@@ -26,6 +26,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.core.resident import ReferenceCache, ResidentSide, build_side
 
 
@@ -178,7 +179,7 @@ class ShardedCorpus:
         Assembly is vectorized, not a per-pair loop: the query-side stats
         tree is stacked once and `repeat`ed S-wise, the cached series-side
         stack is `tile`d Q-wise, and the seed dots run as per-pair f64
-        GEMVs — the SAME `wa[1:] @ wb[0]` / `wb @ wa[0]` products
+        GEMVs — the SAME `zstats.seed_dots` products
         `cross_stats_from_parts` computes, stacked host-side and rounded to
         f32 exactly once — so every pair's streams stay bitwise-identical
         to a fresh `compute_cross_stats_host` build of the same two
@@ -189,7 +190,7 @@ class ShardedCorpus:
         if plan.swap_ab:
             raise ValueError("batched serve plans never swap: the vmapped "
                              "engine row-clamps instead")
-        from repro.core.zstats import CrossStats
+        from repro.core.zstats import CrossStats, seed_dots
 
         nq, ns = len(queries), len(group.sids)
         sides = [self.side(sid) for sid in group.sids]
@@ -201,15 +202,12 @@ class ShardedCorpus:
             lambda x: jnp.tile(x, (nq,) + (1,) * (x.ndim - 1)),
             self._series_stack(group))
 
-        rows = []
-        for _, w_q in queries:
-            wq = np.asarray(w_q, np.float64)
-            for side in sides:
-                wb = np.asarray(side.windows, np.float64)
-                neg = wq[1:] @ wb[0]
-                pos = wb @ wq[0]
-                rows.append(np.concatenate([neg[::-1], pos]))
-        cov0s = jnp.asarray(np.stack(rows), jnp.float32)
+        with obs.span("mp.seeds"):
+            rows = np.stack([seed_dots(w_q, side.windows)
+                             for _, w_q in queries for side in sides])
+        with obs.span("mp.put"):
+            cov0s = jnp.asarray(rows, jnp.float32)
+            obs.count("mp.put_bytes", cov0s.nbytes)
 
         stack = CrossStats(a=a, b=b, cov0s=cov0s)
         if plan.batch is not None:
@@ -223,5 +221,8 @@ class ShardedCorpus:
                         [x, jnp.repeat(x[-1:], pad, axis=0)]), stack)
         dev = group.device
         if dev is not None:
-            stack = jax.device_put(stack, dev)
+            with obs.span("mp.put"):
+                stack = jax.device_put(stack, dev)
+                obs.count("mp.put_bytes", sum(
+                    x.nbytes for x in jax.tree.leaves(stack)))
         return stack

@@ -34,6 +34,7 @@ import time
 
 import numpy as np
 
+from repro import obs
 from repro.serve.queue import AdmissionQueue, PendingQuery
 from repro.serve.rounds import RoundLoop
 
@@ -106,14 +107,15 @@ class ProfileService:
         answers became ready (expirations immediately; batch answers as
         the in-flight window rolls them out — call `drain()` to flush)."""
         now = time.monotonic() if now is None else now
-        answers = [self._expired_answer(q, now)
-                   for q in self.queue.take_expired(now)]
-        batch = self.queue.take_batch(now)
-        if batch:
-            self._dispatch_batch(batch)
-        answers.extend(self._ready)
-        self._ready = []
-        return answers
+        with obs.span("mp.serve.step", batch=self.stats.batches + 1):
+            answers = [self._expired_answer(q, now)
+                       for q in self.queue.take_expired(now)]
+            batch = self.queue.take_batch(now)
+            if batch:
+                self._dispatch_batch(batch)
+            answers.extend(self._ready)
+            self._ready = []
+            return answers
 
     def drain(self) -> list[ServeAnswer]:
         """Deliver every in-flight round and return the finished answers."""
@@ -146,8 +148,9 @@ class ProfileService:
                                     return_centered_windows=True)
                  for q in batch]
         groups = self.corpus.groups()
+        ids = {"batch": self.stats.batches}
         rec = {"batch": batch, "lq": lq, "k": k, "expected": 0,
-               "collected": [], "failed_shards": []}
+               "collected": [], "failed_shards": [], "ids": ids}
         for group in groups:
             tick = self._tick
             self._tick += 1
@@ -167,14 +170,18 @@ class ProfileService:
                 npairs = (q1 - q0) * ns
                 pad = 1 << (npairs - 1).bit_length()  # power-of-two bucket
                 plan = self.corpus.plan_for(group, lq, k=k, batch=pad)
-                stats = self.corpus.assemble_batch(group, parts[q0:q1], plan)
-                res = plan_mod.execute(plan, stats)   # async dispatch
-                if k > 1:
-                    payload = {"d": res.topk_dist, "i": res.topk_index}
-                else:
-                    payload = {"d": res.dist, "i": res.index}
-                rec["expected"] += 1
-                self._loop.dispatch(payload, meta=(rec, group, q0, q1))
+                with obs.span("mp.serve.assemble", **ids):
+                    stats = self.corpus.assemble_batch(group, parts[q0:q1],
+                                                       plan)
+                with obs.span("mp.serve.dispatch", **ids):
+                    res = plan_mod.execute(plan, stats)   # async dispatch
+                    if k > 1:
+                        payload = {"d": res.topk_dist, "i": res.topk_index}
+                    else:
+                        payload = {"d": res.dist, "i": res.index}
+                    rec["expected"] += 1
+                    self._loop.dispatch(payload, meta=(rec, group, q0, q1),
+                                        ids=ids)
         if rec["expected"] == 0:
             self._finalize(rec)                       # every shard failed
 
@@ -206,47 +213,48 @@ class ProfileService:
 
         from repro.core.matrix_profile import TopKState
 
-        batch, lq, k = rec["batch"], rec["lq"], rec["k"]
-        nq, stride = len(batch), self._stride
-        state = TopKState(corr=jnp.full((nq, lq, k), -jnp.inf, jnp.float32),
-                          index=jnp.full((nq, lq, k), -1, jnp.int32))
-        covered = 0
-        for group, q0, q1, payload in rec["collected"]:
-            ns, nb = len(group.sids), q1 - q0
-            if q0 == 0:                   # count each group's series once
-                covered += ns
-            d = jnp.asarray(payload["d"])[:nb * ns]
-            i = jnp.asarray(payload["i"])[:nb * ns]
-            if k == 1:
-                d, i = d[..., None], i[..., None]
-            # rows are query-major: (q * S + s) -> (Q, S, lq, k); pack the
-            # neighbor as a single id so the union is one top_k
-            d = jnp.moveaxis(d.reshape(nb, ns, lq, k), 1, 2)
-            i = jnp.moveaxis(i.reshape(nb, ns, lq, k), 1, 2)
-            sid = jnp.asarray(group.sids, jnp.int32)[None, None, :, None]
-            packed = jnp.where(i >= 0, sid * stride + i, -1)
-            cand = TopKState(corr=(-d).reshape(nb, lq, ns * k),
-                             index=packed.reshape(nb, lq, ns * k))
-            # exact union: shards hold disjoint series, so no neighbor is
-            # offered twice (allreduce_topk's argument, applied to shards)
-            part = TopKState(corr=state.corr[q0:q1],
-                             index=state.index[q0:q1]).merge(cand)
-            state = TopKState(corr=state.corr.at[q0:q1].set(part.corr),
-                              index=state.index.at[q0:q1].set(part.index))
-        dist = np.asarray(-state.corr)
-        packed = np.asarray(state.index)
-        pos = np.where(packed >= 0, packed % stride, -1).astype(np.int32)
-        sid = np.where(packed >= 0, packed // stride, -1).astype(np.int32)
-        coverage = covered / self.corpus.n_series
-        degraded = coverage < 1.0
-        now = time.monotonic()
-        for n, q in enumerate(batch):
-            self._ready.append(self._make_answer(
-                q, dist[n], pos[n], sid[n], k, coverage,
-                "degraded" if degraded else "ok",
-                now, tuple(rec["failed_shards"])))
-        self.queue.mark_completed(len(batch),
-                                  degraded=len(batch) if degraded else 0)
+        with obs.span("mp.serve.merge", **rec["ids"]):
+            batch, lq, k = rec["batch"], rec["lq"], rec["k"]
+            nq, stride = len(batch), self._stride
+            state = TopKState(corr=jnp.full((nq, lq, k), -jnp.inf, jnp.float32),
+                              index=jnp.full((nq, lq, k), -1, jnp.int32))
+            covered = 0
+            for group, q0, q1, payload in rec["collected"]:
+                ns, nb = len(group.sids), q1 - q0
+                if q0 == 0:                   # count each group's series once
+                    covered += ns
+                d = jnp.asarray(payload["d"])[:nb * ns]
+                i = jnp.asarray(payload["i"])[:nb * ns]
+                if k == 1:
+                    d, i = d[..., None], i[..., None]
+                # rows are query-major: (q * S + s) -> (Q, S, lq, k); pack the
+                # neighbor as a single id so the union is one top_k
+                d = jnp.moveaxis(d.reshape(nb, ns, lq, k), 1, 2)
+                i = jnp.moveaxis(i.reshape(nb, ns, lq, k), 1, 2)
+                sid = jnp.asarray(group.sids, jnp.int32)[None, None, :, None]
+                packed = jnp.where(i >= 0, sid * stride + i, -1)
+                cand = TopKState(corr=(-d).reshape(nb, lq, ns * k),
+                                 index=packed.reshape(nb, lq, ns * k))
+                # exact union: shards hold disjoint series, so no neighbor is
+                # offered twice (allreduce_topk's argument, applied to shards)
+                part = TopKState(corr=state.corr[q0:q1],
+                                 index=state.index[q0:q1]).merge(cand)
+                state = TopKState(corr=state.corr.at[q0:q1].set(part.corr),
+                                  index=state.index.at[q0:q1].set(part.index))
+            dist = np.asarray(-state.corr)
+            packed = np.asarray(state.index)
+            pos = np.where(packed >= 0, packed % stride, -1).astype(np.int32)
+            sid = np.where(packed >= 0, packed // stride, -1).astype(np.int32)
+            coverage = covered / self.corpus.n_series
+            degraded = coverage < 1.0
+            now = time.monotonic()
+            for n, q in enumerate(batch):
+                self._ready.append(self._make_answer(
+                    q, dist[n], pos[n], sid[n], k, coverage,
+                    "degraded" if degraded else "ok",
+                    now, tuple(rec["failed_shards"])))
+            self.queue.mark_completed(len(batch),
+                                      degraded=len(batch) if degraded else 0)
 
     def _make_answer(self, q: PendingQuery, dist, pos, sid, k: int,
                      coverage: float, status: str, now: float,

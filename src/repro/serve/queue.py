@@ -11,7 +11,8 @@ The batcher is geometry-bucketing: compatible queries — same subsequence
 count and k — batch into ONE vmapped sweep, and the bucket containing the
 OLDEST pending query is served first (no starvation: age, not bucket size,
 picks the next batch). `QueueStats` counts every admission decision so
-rejection/backpressure behavior is observable, not inferred.
+rejection/backpressure behavior is observable, not inferred, and how long
+the queries taken into batches waited for it.
 """
 
 from __future__ import annotations
@@ -22,6 +23,8 @@ import time
 
 import numpy as np
 
+from repro import obs
+
 
 class QueryRejected(RuntimeError):
     """Raised at submit time when the queue is full (backpressure)."""
@@ -31,7 +34,9 @@ class QueryRejected(RuntimeError):
 class QueueStats:
     """Admission counters — every submitted query ends in exactly one of
     completed/rejected/expired (degraded completions count in BOTH
-    `completed` and `degraded`)."""
+    `completed` and `degraded`). `taken` counts the queries taken into
+    batches and `waited_s` sums their take time minus submit time, on the
+    queue's clock."""
 
     submitted: int = 0
     accepted: int = 0
@@ -40,6 +45,8 @@ class QueueStats:
     completed: int = 0
     degraded: int = 0
     batches: int = 0
+    taken: int = 0
+    waited_s: float = 0.0
 
     @property
     def pending(self) -> int:
@@ -107,11 +114,13 @@ class AdmissionQueue:
         sat in the queue — the front-end turns these into coverage-0
         degraded answers."""
         now = time.monotonic() if now is None else now
-        out = [q for q in self._pending if q.expired(now)]
-        if out:
-            self._pending = [q for q in self._pending if not q.expired(now)]
-            self.stats.expired += len(out)
-        return out
+        with obs.span("mp.serve.take", batch=self.stats.batches + 1):
+            out = [q for q in self._pending if q.expired(now)]
+            if out:
+                self._pending = [q for q in self._pending
+                                 if not q.expired(now)]
+                self.stats.expired += len(out)
+            return out
 
     def take_batch(self, now: float | None = None) -> list[PendingQuery]:
         """Remove and return the next geometry-compatible batch: every
@@ -120,14 +129,18 @@ class AdmissionQueue:
         if not self._pending:
             return []
         now = time.monotonic() if now is None else now
-        head = self._pending[0]
-        key = (head.l_q, head.k)
-        batch = [q for q in self._pending
-                 if (q.l_q, q.k) == key][:self.max_batch]
-        taken = set(id(q) for q in batch)
-        self._pending = [q for q in self._pending if id(q) not in taken]
-        self.stats.batches += 1
-        return batch
+        with obs.span("mp.serve.take", batch=self.stats.batches + 1):
+            head = self._pending[0]
+            key = (head.l_q, head.k)
+            batch = [q for q in self._pending
+                     if (q.l_q, q.k) == key][:self.max_batch]
+            taken = set(id(q) for q in batch)
+            self._pending = [q for q in self._pending
+                             if id(q) not in taken]
+            self.stats.batches += 1
+            self.stats.taken += len(batch)
+            self.stats.waited_s += sum(now - q.submitted_at for q in batch)
+            return batch
 
     def mark_completed(self, n: int = 1, *, degraded: int = 0) -> None:
         self.stats.completed += n

@@ -23,6 +23,8 @@ from __future__ import annotations
 
 from collections import deque
 
+from repro import obs
+
 
 class RoundLoop:
     """Bounded in-flight window over asynchronously dispatched rounds."""
@@ -42,16 +44,17 @@ class RoundLoop:
     def __len__(self) -> int:
         return len(self._inflight)
 
-    def dispatch(self, payload, meta=None) -> None:
+    def dispatch(self, payload, meta=None, ids=None) -> None:
         """Track one dispatched round. `payload` is any pytree of device
         arrays the caller's sweep already launched; delivery blocks on it.
         If the window is full, the oldest round is delivered (blocking on
         ITS arrays — by then usually already complete) before this one is
         admitted, so dispatch order == delivery order and memory stays
-        bounded."""
+        bounded. `ids` (say `{"batch": 3}`) tag the round's
+        `mp.serve.deliver` span."""
         while len(self._inflight) >= self.depth:
             self.deliver_next()
-        self._inflight.append((meta, payload))
+        self._inflight.append((meta, payload, ids or {}))
         self.dispatched += 1
 
     def deliver_next(self):
@@ -61,12 +64,13 @@ class RoundLoop:
 
         if not self._inflight:
             raise RuntimeError("no rounds in flight")
-        meta, payload = self._inflight.popleft()
-        payload = jax.block_until_ready(payload)
-        self.delivered += 1
-        if self._deliver is not None:
-            self._deliver(meta, payload)
-        return meta, payload
+        meta, payload, ids = self._inflight.popleft()
+        with obs.span("mp.serve.deliver", **ids):
+            payload = jax.block_until_ready(payload)
+            self.delivered += 1
+            if self._deliver is not None:
+                self._deliver(meta, payload)
+            return meta, payload
 
     def drain(self) -> list:
         """Deliver every remaining in-flight round, dispatch order."""
