@@ -375,9 +375,10 @@ def resident_stats(plan: SweepPlan, query, resident):
     (`StreamingProfile.query`, `serve.ShardedCorpus`) never orient the
     rectangle by hand.
 
-    Assembly runs through `zstats.cross_stats_from_parts` — the exact same
-    seed-dot path `compute_cross_stats_host` uses internally, so a
-    resident-side payload is bitwise-identical to building both sides fresh.
+    Assembly runs through `zstats.cross_stats_from_parts`, whose seed dots
+    run the same row-block routine as `compute_cross_stats_host`'s window
+    pass, so a resident-side payload is bitwise-identical to building both
+    sides fresh.
     Raw (nonnorm) plans return the `(query, corpus_ts)` series tuple the
     nonnorm executor expects. Resident caching stores only the default-
     precision streams, so non-default precision plans are rejected rather

@@ -40,12 +40,25 @@ them without schema changes:
 from __future__ import annotations
 
 import dataclasses
+import os
+from concurrent.futures import ThreadPoolExecutor
 from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import obs
+
+# Bytes of f64 centered windows per row block of the host window pass
+# (`_window_pass`): 256 rows at m = 1024. A block is built, read for its
+# norms and probe dots while it is in cache, and overwritten by the next.
+# It stays below the 460800 elements (3.5 MiB of f64) from which the
+# OpenBLAS that numpy bundles (0.3.27) runs a GEMV on its own threads, so
+# a block's dots are one single-threaded call on whichever thread runs it.
+# The fresh pass runs as fast at 1 MiB on a TPU v5e host; 2 MiB halves the
+# calls of the pooled passes over stored matrices (PERF.md, PR 14).
+WINDOW_BLOCK_BYTES = 1 << 21
 
 
 @jax.tree_util.register_dataclass
@@ -124,7 +137,8 @@ def cross_stats_from_parts(stats_a: ZStats, wa, stats_b: ZStats, wb,
     windows)` pairs `compute_stats_host(..., return_centered_windows=True)`
     yields. This is the seam that lets a RESIDENT side be computed once and
     reused across joins (StreamingProfile.query caches its corpus side this
-    way); `compute_cross_stats_host` is the build-both-sides convenience.
+    way); `compute_cross_stats_host` builds both sides fresh without the
+    matrices and gives the same seeds bit for bit.
 
     The seeds are exact f64 centered-window dots, so the device recurrence
     restarts from well-conditioned values on every diagonal. Each stats pass
@@ -137,6 +151,10 @@ def cross_stats_from_parts(stats_a: ZStats, wa, stats_b: ZStats, wb,
     """
     with obs.span("mp.seeds"):
         seeds = seed_dots(wa, wb)
+    return _assemble_cross(stats_a, stats_b, seeds, out_dtype, seed_dtype)
+
+
+def _assemble_cross(stats_a, stats_b, seeds, out_dtype, seed_dtype):
     if seed_dtype is None:
         seed_dtype = out_dtype
     dt = jnp.float32 if seed_dtype is None else seed_dtype
@@ -146,28 +164,46 @@ def cross_stats_from_parts(stats_a: ZStats, wa, stats_b: ZStats, wb,
     return CrossStats(a=stats_a, b=stats_b, cov0s=cov0s)
 
 
+def _diagonal_seeds(neg, pos):
+    """Order the two sides' probe dots by diagonal, index k + l_a - 1:
+    `neg[i]` = <A_i, B_0> seeds k = -i (row 0 is diagonal 0, which `pos`
+    carries), `pos[j]` = <A_0, B_j> seeds k = j."""
+    return np.concatenate([neg[1:][::-1], pos])
+
+
 def seed_dots(wa, wb):
     """The f64 seed of every diagonal of the (l_a, l_b) rectangle, indexed
     k + l_a - 1, from the two sides' f64 centered-window matrices: exact
-    window dots at each diagonal's first cell."""
-    import numpy as np
+    window dots at each diagonal's first cell. Runs the same row-block dots
+    as the window pass of `compute_cross_stats_host`, so a stored matrix
+    and a fresh build give the same bits."""
+    return seed_dots_batch([wa], [wb])[0]
 
-    wa = np.asarray(wa, np.float64)
-    wb = np.asarray(wb, np.float64)
-    neg = wa[1:] @ wb[0]            # k = -1 .. -(l_a-1), start cells (-k, 0)
-    pos = wb @ wa[0]                # k = 0 .. l_b-1,     start cells (0, k)
-    return np.concatenate([neg[::-1], pos])
+
+def seed_dots_batch(was, wbs):
+    """`seed_dots` of every pair of stored matrices, A-major: item
+    `i * len(wbs) + j` is `was[i]` against `wbs[j]`, the same bits as the
+    pair alone. Each matrix is read once for all the other side's first
+    windows, and the passes run on the thread pool (`_stored_dots`)."""
+    was = [np.asarray(w, np.float64) for w in was]
+    wbs = [np.asarray(w, np.float64) for w in wbs]
+    dots = _stored_dots([(wb, [wa[0] for wa in was]) for wb in wbs]
+                        + [(wa, [wb[0] for wb in wbs]) for wa in was])
+    pos, neg = dots[:len(wbs)], dots[len(wbs):]
+    return [_diagonal_seeds(neg[i][j], pos[j][i])
+            for i in range(len(was)) for j in range(len(wbs))]
 
 
 def compute_cross_stats_host(ts_a, ts_b, window: int, out_dtype=None,
                              seed_dtype=None) -> CrossStats:
     """Build AB-join streams host-side in f64 (same rationale as
-    `compute_stats_host`), then assemble via `cross_stats_from_parts`.
+    `compute_stats_host`) without any (l, m) window matrix.
 
-    The seed dots reuse the centered-window matrices the stats pass already
-    built (`return_centered_windows=True`), so each series' (l, m) window
-    matrix is materialized exactly ONCE — half the AB host-prep time and
-    peak memory of building it again for the seeds.
+    Each side's streamed window pass also takes every row's dot with the
+    OTHER side's first centered window: side A's rows against B_0 seed the
+    negative diagonals, side B's rows against A_0 the others. The seeds are
+    bitwise-equal to `cross_stats_from_parts` over the stored matrices (the
+    resident paths).
 
     Either side may be as short as one window (n >= m): query-against-corpus
     joins legitimately use a short side in both orientations (short query vs
@@ -175,20 +211,16 @@ def compute_cross_stats_host(ts_a, ts_b, window: int, out_dtype=None,
     """
     m = int(window)
     with obs.span("mp.stats"):
-        sa, wa = compute_stats_host(ts_a, m, out_dtype=out_dtype,
-                                    seed_dtype=seed_dtype,
-                                    min_subsequences=1,
-                                    return_centered_windows=True)
-        sb, wb = compute_stats_host(ts_b, m, out_dtype=out_dtype,
-                                    seed_dtype=seed_dtype,
-                                    min_subsequences=1,
-                                    return_centered_windows=True)
-        cross = cross_stats_from_parts(sa, wa, sb, wb, out_dtype=out_dtype,
-                                       seed_dtype=seed_dtype)
-        # free the window matrices (GBs for long series) inside the span:
-        # releasing them is part of the prep's cost
-        del wa, wb
-    return cross
+        ta, mu_a, masked_a = _prepare(ts_a, m, 1)
+        tb, mu_b, masked_b = _prepare(ts_b, m, 1)
+        a0, b0 = ta[:m] - mu_a[0], tb[:m] - mu_b[0]
+        sa, (neg,), _ = _side_stats(ta, mu_a, masked_a, m, [b0],
+                                    out_dtype, seed_dtype)
+        sb, (pos,), _ = _side_stats(tb, mu_b, masked_b, m, [a0],
+                                    out_dtype, seed_dtype)
+        with obs.span("mp.seeds"):
+            seeds = _diagonal_seeds(neg, pos)
+        return _assemble_cross(sa, sb, seeds, out_dtype, seed_dtype)
 
 
 def moving_mean_var(ts: jax.Array, m: int) -> tuple[jax.Array, jax.Array]:
@@ -304,10 +336,11 @@ def compute_stats_host(ts, window: int, out_dtype=None, seed_dtype=None,
     `min_subsequences` relaxes the self-join-oriented n >= 2m check: the B
     side of an AB join only needs n >= m + min_subsequences - 1.
 
-    `return_centered_windows=True` returns `(stats, w)` where `w` is the f64
-    (l, m) centered-window matrix the pass built anyway — callers needing
-    exact window dots (AB seed covariances) reuse it instead of
-    re-materializing O(l*m) memory.
+    The centered windows are streamed in row blocks (`_window_pass`) and
+    never held all at once. `return_centered_windows=True` instead builds
+    the f64 (l, m) centered-window matrix and returns `(stats, w)`, for
+    callers that keep it to take exact window dots later (the resident
+    corpus sides); the stats are the same bits either way.
 
     NaN/Inf samples are accepted: every subsequence touching one is masked
     via the invn = -1 sentinel (see module docstring) — its profile entries
@@ -316,72 +349,172 @@ def compute_stats_host(ts, window: int, out_dtype=None, seed_dtype=None,
     samples are filled with the finite mean before the cumsums, and a valid
     window's stats depend only on its own samples).
     """
-    import numpy as np
-
+    m = int(window)
     with obs.span("mp.stats"):
-        t = np.asarray(ts, np.float64)
-        if t.ndim != 1:
-            raise ValueError(f"time series must be 1-D, got shape {t.shape}")
-        m = int(window)
-        n = t.shape[0]
-        min_n = 2 * m if min_subsequences is None else m + int(min_subsequences) - 1
-        if n < min_n:
-            raise ValueError(f"series too short: n={n} < {min_n} "
-                             f"(window={m}, min_subsequences={min_subsequences})")
-        finite = np.isfinite(t)
-        masked = None
-        if not finite.all():
-            # fill gaps with the finite mean so every downstream cumsum/dot is
-            # finite; windows touching a gap are flagged and get the invn = -1
-            # sentinel below (their other stream values are don't-cares — every
-            # engine masks their cells before any harvest)
-            fill = t[finite].mean() if finite.any() else 0.0
-            t = np.where(finite, t, fill)
-            nbad = np.concatenate([[0], np.cumsum(~finite)])
-            masked = (nbad[m:] - nbad[:-m]) > 0            # (l,) touches a gap
-        t = t - t.mean()                      # shift-invariant; improves f32 casts
-        l = n - m + 1
-        csum = np.concatenate([[0.0], np.cumsum(t)])
-        mu = (csum[m:] - csum[:-m]) / m
-        # zero-copy window view instead of an (l, m) index-gather: stats prep is
-        # on the timed serving path, so the only O(l*m) materialization is the
-        # centered matrix itself
-        view = np.lib.stride_tricks.sliding_window_view(t, m)
-        w = view - mu[:, None]                # exact two-pass centering
-        obs.count("mp.window_bytes", w.nbytes)
-        norm = np.sqrt(np.einsum("lm,lm->l", w, w))
-        # flat-window guard must be RELATIVE: cumsum roundoff in mu leaves
-        # ~1e-15-relative residues in w for constant windows, and an exact
-        # norm > 0 test would then emit invn ~ 1e15 instead of the corr-0
-        # convention. Windows whose deviation is below 1e-8 of their magnitude
-        # are z-norm-degenerate either way. scale^2 = sum(t[idx]^2) is
-        # norm^2 + m*mu^2 (sum of deviations is ~0), so no second window pass.
-        scale2 = norm * norm + m * mu * mu
-        flat = norm * norm <= 1e-16 * np.maximum(scale2, 1e-300)
-        invn = np.where(~flat & (norm > 0), 1.0 / np.maximum(norm, 1e-300), 0.0)
-        if masked is not None:
-            invn = np.where(masked, -1.0, invn)   # missing-data sentinel
-        tail, head = t[m:], t[: l - 1]
-        df = np.concatenate([[0.0], (tail[: l - 1] - head) / 2.0])
-        dg = np.concatenate([[0.0], (tail[: l - 1] - mu[1:]) + (head - mu[:-1])])
-        with obs.span("mp.seeds"):
-            cov0 = w @ w[0]
-        if not return_centered_windows:
-            # free the matrix (4.3 GB at n = 2^19, m = 1024) inside the span
-            # and before the streams' copies are enqueued
-            del w
-        dt = jnp.float32 if out_dtype is None else out_dtype
-        sdt = dt if seed_dtype is None else seed_dtype
-        # single rounding f64 -> target dtype (never through an f32 staging cast)
-        f = lambda x, d=dt: jnp.asarray(np.asarray(x, np.float64), d)
-        with obs.span("mp.put"):
-            stats = ZStats(ts=f(t), mu=f(mu), invn=f(invn), df=f(df), dg=f(dg),
-                           cov0=f(cov0, sdt), window=m)
-            obs.count("mp.put_bytes",
-                      sum(x.nbytes for x in jax.tree.leaves(stats)))
+        t, mu, masked = _prepare(ts, m, min_subsequences)
+        stats, _, w = _side_stats(t, mu, masked, m, [], out_dtype,
+                                  seed_dtype, return_centered_windows)
         if return_centered_windows:
             return stats, w
         return stats
+
+
+def _prepare(ts, m: int, min_subsequences: int | None):
+    """The f64 series, shifted to mean 0 with any non-finite samples filled,
+    its window means, and the (l,) mask of windows touching a gap (None
+    when every sample is finite)."""
+    t = np.asarray(ts, np.float64)
+    if t.ndim != 1:
+        raise ValueError(f"time series must be 1-D, got shape {t.shape}")
+    n = t.shape[0]
+    min_n = 2 * m if min_subsequences is None else m + int(min_subsequences) - 1
+    if n < min_n:
+        raise ValueError(f"series too short: n={n} < {min_n} "
+                         f"(window={m}, min_subsequences={min_subsequences})")
+    finite = np.isfinite(t)
+    masked = None
+    if not finite.all():
+        # fill gaps with the finite mean so every downstream cumsum/dot is
+        # finite; windows touching a gap are flagged and get the invn = -1
+        # sentinel below (their other stream values are don't-cares — every
+        # engine masks their cells before any harvest)
+        fill = t[finite].mean() if finite.any() else 0.0
+        t = np.where(finite, t, fill)
+        nbad = np.concatenate([[0], np.cumsum(~finite)])
+        masked = (nbad[m:] - nbad[:-m]) > 0            # (l,) touches a gap
+    t = t - t.mean()                      # shift-invariant; improves f32 casts
+    csum = np.concatenate([[0.0], np.cumsum(t)])
+    mu = (csum[m:] - csum[:-m]) / m
+    return t, mu, masked
+
+
+def _side_stats(t, mu, masked, m: int, probes, out_dtype, seed_dtype,
+                keep_windows: bool = False):
+    """One series' streams from `_prepare`'s parts, plus each row's dot with
+    every extra probe window. Returns `(stats, probe dots, w)`; `w` is the
+    (l, m) centered-window matrix when `keep_windows`, else None and no
+    matrix is built."""
+    l = mu.shape[0]
+    # zero-copy window view instead of an (l, m) index-gather
+    view = np.lib.stride_tricks.sliding_window_view(t, m)
+    w = None
+    if keep_windows:
+        w = view - mu[:, None]                # exact two-pass centering
+        obs.count("mp.window_bytes", w.nbytes)
+        norm2, dots = _window_pass(w, [*probes, w[0]])
+    else:
+        norm2, dots = _window_pass(view, [*probes, view[0] - mu[0]], mu)
+    norm = np.sqrt(norm2)
+    # flat-window guard must be RELATIVE: cumsum roundoff in mu leaves
+    # ~1e-15-relative residues in w for constant windows, and an exact
+    # norm > 0 test would then emit invn ~ 1e15 instead of the corr-0
+    # convention. Windows whose deviation is below 1e-8 of their magnitude
+    # are z-norm-degenerate either way. scale^2 = sum(t[idx]^2) is
+    # norm^2 + m*mu^2 (sum of deviations is ~0), so no second window pass.
+    scale2 = norm * norm + m * mu * mu
+    flat = norm * norm <= 1e-16 * np.maximum(scale2, 1e-300)
+    invn = np.where(~flat & (norm > 0), 1.0 / np.maximum(norm, 1e-300), 0.0)
+    if masked is not None:
+        invn = np.where(masked, -1.0, invn)   # missing-data sentinel
+    tail, head = t[m:], t[: l - 1]
+    df = np.concatenate([[0.0], (tail[: l - 1] - head) / 2.0])
+    dg = np.concatenate([[0.0], (tail[: l - 1] - mu[1:]) + (head - mu[:-1])])
+    with obs.span("mp.seeds"):
+        cov0 = dots[-1]                       # taken by the window pass
+    dt = jnp.float32 if out_dtype is None else out_dtype
+    sdt = dt if seed_dtype is None else seed_dtype
+    # single rounding f64 -> target dtype (never through an f32 staging cast)
+    f = lambda x, d=dt: jnp.asarray(np.asarray(x, np.float64), d)
+    with obs.span("mp.put"):
+        stats = ZStats(ts=f(t), mu=f(mu), invn=f(invn), df=f(df), dg=f(dg),
+                       cov0=f(cov0, sdt), window=m)
+        obs.count("mp.put_bytes",
+                  sum(x.nbytes for x in jax.tree.leaves(stats)))
+    return stats, dots[:-1], w
+
+
+def _block_rows(m: int) -> int:
+    """Rows of one block of the window pass: WINDOW_BLOCK_BYTES of f64."""
+    return max(1, WINDOW_BLOCK_BYTES // (8 * m))
+
+
+def _window_pass(w, probes, mu=None, norms: bool = True):
+    """Squared norms of the (l, m) centered windows and each window's dot
+    with every probe (an (m,) f64 vector), block of `_block_rows(m)` rows
+    by block, so each block is read while it is still in cache.
+
+    `w` is the centered-window matrix, or, given the window means `mu`, the
+    series' zero-copy window view: each block is then centered into one
+    reused buffer (`mp.window_blocks` counts those blocks). The blocks have
+    the same boundaries and the same per-row arithmetic either way, so a
+    stored matrix and a streamed pass give the same bits. Returns `(norm2
+    or None, (len(probes), l) dots)`."""
+    l, m = w.shape
+    norm2 = np.empty(l) if norms else None
+    dots = np.empty((len(probes), l))
+    _blocks(w, probes, mu, norm2, dots, 0, l)
+    if mu is not None:
+        obs.count("mp.window_blocks", -(-l // _block_rows(m)))
+    return norm2, dots
+
+
+def _blocks(w, probes, mu, norm2, dots, start, stop):
+    """The window pass over rows [start, stop) of `w`, into `norm2` (if not
+    None) and `dots`; `start` lies on a block boundary."""
+    m = w.shape[1]
+    rows = _block_rows(m)
+    buf = None if mu is None else np.empty((min(rows, stop - start), m))
+    for s in range(start, stop, rows):
+        e = min(s + rows, stop)
+        blk = w[s:e]
+        if buf is not None:
+            blk = np.subtract(blk, mu[s:e, None], out=buf[: e - s])
+        if norm2 is not None:
+            norm2[s:e] = np.einsum("lm,lm->l", blk, blk)
+        for d, p in zip(dots, probes):
+            np.dot(blk, p, out=d[s:e])       # unlike `@`, frees the GIL
+
+
+def _stored_dots(passes):
+    """`_window_pass(w, probes, norms=False)` dots of each `(w, probes)` of
+    stored matrices, on the thread pool. Each matrix is cut into runs of
+    whole blocks, enough runs in all to give every thread work (one per
+    matrix when there are as many matrices as threads). The blocks are
+    those of the pass, so the bits are too. A block's GEMV is too small
+    for OpenBLAS's own threads, and the pool's threads overlap only about
+    three-fold (each call returns through the GIL): on a TPU v5e host this
+    is half the speed of one threaded GEMV over a whole matrix (PERF.md,
+    PR 14)."""
+    outs = [np.empty((len(probes), w.shape[0])) for w, probes in passes]
+    per = -(-_THREADS // len(passes))
+    jobs = []
+    for (w, probes), out in zip(passes, outs):
+        l, rows = w.shape[0], _block_rows(w.shape[1])
+        n = -(-l // rows)
+        cuts = [rows * (i * n // per) for i in range(per)] + [l]
+        jobs += [(w, probes, out, a, b) for a, b in zip(cuts, cuts[1:])
+                 if a < b]
+
+    def run(job):
+        w, probes, out, start, stop = job
+        _blocks(w, probes, None, None, out, start, stop)
+
+    list(_pool().map(run, jobs) if len(jobs) > 1 else map(run, jobs))
+    return outs
+
+
+_THREADS = len(os.sched_getaffinity(0))
+_POOL: tuple | None = None     # (pid, executor) of this process
+
+
+def _pool() -> ThreadPoolExecutor:
+    """One thread per CPU this process may run on, for `_stored_dots`. The
+    GIL is released in each block's GEMV. Made anew in a forked child,
+    whose parent's threads are gone."""
+    global _POOL
+    if _POOL is None or _POOL[0] != os.getpid():
+        _POOL = (os.getpid(), ThreadPoolExecutor(_THREADS))
+    return _POOL[1]
 
 
 # -- shared streaming/fleet block distances -----------------------------------

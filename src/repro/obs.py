@@ -9,10 +9,16 @@ profiler keeps nothing and an entry costs about a microsecond.
 Span names carry the `mp.` prefix. A span's self time is its duration minus
 the time its child `mp.` spans on the same thread cover:
 
-  mp.stats           the f64 host prep of one series (window matrix, norms,
-                     streams, the matrix's release), or of both sides in
-                     `compute_cross_stats_host`; holds mp.seeds and mp.put
-  mp.seeds           the f64 seed dots
+  mp.stats           the f64 host prep of one series (the window pass in
+                     row blocks: norms and probe dots; the streams), or of
+                     both sides under one span in `compute_cross_stats_host`;
+                     holds mp.seeds and mp.put. No window matrix is built
+                     unless the caller keeps one (`return_centered_windows`)
+  mp.seeds           on the fresh entries a near-empty span: the seed dots
+                     are taken inside the window pass (mp.stats), and this
+                     span only picks `cov0` or orders the `cov0s`; on the
+                     resident paths, the f64 seed dots over stored window
+                     matrices (`zstats.seed_dots`)
   mp.put             enqueuing the host -> device copies of streams and
                      seeds (the copies themselves run asynchronously)
   mp.sweep           `plan.execute` up to and including the sweep's launch;
@@ -29,6 +35,10 @@ the time its child `mp.` spans on the same thread cover:
 Counters are plain running sums, kept where the work happens:
 
   mp.window_bytes    bytes of the f64 (l, m) centered-window matrices built
+                     (only callers that keep one: the resident sides, the
+                     service's queries)
+  mp.window_blocks   row blocks of centered windows the streamed window
+                     pass built (`zstats._window_pass`)
   mp.put_bytes       bytes the mp.put spans copy to the device
 """
 

@@ -12,8 +12,8 @@ compute runs — queries ship O(l_q) query streams, never corpus state
 Series are grouped by (shard, length): a group is the unit of batched
 execution — Q queries against its S series stack into one `(Q*S)`-wide
 vmapped engine sweep (`assemble_batch` builds the stacked `CrossStats`
-per-pair through `zstats.cross_stats_from_parts`, the exact seed-dot path
-`compute_cross_stats_host` uses, so every pair is bitwise-identical to a
+with `zstats.seed_dots_batch`, the row-block seed dots
+`compute_cross_stats_host` takes, so every pair is bitwise-identical to a
 fresh two-sided build). Content changes go through `reload(sid, values)`,
 which bumps the series' generation — the shared `ReferenceCache` keys sides
 by it, so stale stats can never be served (same contract as the streaming
@@ -178,8 +178,8 @@ class ShardedCorpus:
 
         Assembly is vectorized, not a per-pair loop: the query-side stats
         tree is stacked once and `repeat`ed S-wise, the cached series-side
-        stack is `tile`d Q-wise, and the seed dots run as per-pair f64
-        GEMVs — the SAME `zstats.seed_dots` products
+        stack is `tile`d Q-wise, and the seed dots of every pair run in
+        one `zstats.seed_dots_batch` — the SAME `zstats.seed_dots` products
         `cross_stats_from_parts` computes, stacked host-side and rounded to
         f32 exactly once — so every pair's streams stay bitwise-identical
         to a fresh `compute_cross_stats_host` build of the same two
@@ -190,7 +190,7 @@ class ShardedCorpus:
         if plan.swap_ab:
             raise ValueError("batched serve plans never swap: the vmapped "
                              "engine row-clamps instead")
-        from repro.core.zstats import CrossStats, seed_dots
+        from repro.core.zstats import CrossStats, seed_dots_batch
 
         nq, ns = len(queries), len(group.sids)
         sides = [self.side(sid) for sid in group.sids]
@@ -203,8 +203,8 @@ class ShardedCorpus:
             self._series_stack(group))
 
         with obs.span("mp.seeds"):
-            rows = np.stack([seed_dots(w_q, side.windows)
-                             for _, w_q in queries for side in sides])
+            rows = np.stack(seed_dots_batch([w_q for _, w_q in queries],
+                                            [s.windows for s in sides]))
         with obs.span("mp.put"):
             cov0s = jnp.asarray(rows, jnp.float32)
             obs.count("mp.put_bytes", cov0s.nbytes)

@@ -74,29 +74,68 @@ def test_seeds_and_put_nest_inside_stats_on_one_thread(spans):
             n_stats += 1
             inside = {n for s2, e2, n, _ in events if s <= s2 and e2 <= e}
             assert {"mp.seeds", "mp.put"} <= inside
-    # the self-join series, both AB sides and the service's one query
-    assert n_stats >= 4
+    # one span per prep: the self-join series, the AB join (both sides
+    # under one span) and the service's one query
+    assert n_stats == 3
 
 
 def test_window_bytes_count_each_centered_window_matrix():
+    from repro.core.resident import build_side
+
     rng = np.random.default_rng(3)
     m = 16
     a, b = rng.normal(size=200), rng.normal(size=90)
+    l_a, l_b = a.size - m + 1, b.size - m + 1
     before = obs.counters()
     compute_stats_host(a, m)
     mid = obs.counters()
     compute_cross_stats_host(a, b, m)
     after = obs.counters()
-    l_a, l_b = a.size - m + 1, b.size - m + 1
-    assert mid["mp.window_bytes"] - before.get("mp.window_bytes", 0) \
-        == l_a * m * 8
-    assert after["mp.window_bytes"] - mid["mp.window_bytes"] \
-        == (l_a + l_b) * m * 8
+    build_side(a, m)
+    kept = obs.counters()
+    # the fresh entries stream their windows; a resident side keeps its
+    # matrix
+    wb = [c.get("mp.window_bytes", 0) for c in (before, mid, after, kept)]
+    assert wb[0] == wb[1] == wb[2]
+    assert wb[3] - wb[2] == l_a * m * 8
+    assert kept["mp.window_blocks"] == after["mp.window_blocks"]
     # six f32 streams of one series, then two sides and the f32 seeds
     assert mid["mp.put_bytes"] - before.get("mp.put_bytes", 0) \
         == 4 * (a.size + 5 * l_a)
     assert after["mp.put_bytes"] - mid["mp.put_bytes"] \
         == 4 * (a.size + 5 * l_a + b.size + 5 * l_b + l_a + l_b - 1)
+
+
+def test_fresh_stats_stream_row_blocks_in_bounded_memory():
+    """At n = 2^18, m = 1024 the (l, m) f64 window matrix would be 2.1 GB
+    per side: the fresh entries build none, stay under 64 MB of host
+    allocations, and stream each side in ceil(l / rows) row blocks."""
+    import tracemalloc
+
+    from repro.core import zstats
+
+    m = 1024
+    rng = np.random.default_rng(11)
+    a = np.cumsum(rng.normal(size=2 ** 18))
+    b = np.cumsum(rng.normal(size=2 ** 18 - 4099))
+    l_a, l_b = a.size - m + 1, b.size - m + 1
+    blocks = lambda l: -(-l // zstats._block_rows(m))
+    for run, sides in ((lambda: compute_stats_host(a, m), [l_a]),
+                       (lambda: compute_cross_stats_host(a, b, m),
+                        [l_a, l_b])):
+        before = obs.counters()
+        tracemalloc.start()
+        try:
+            run()
+            _, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+        after = obs.counters()
+        assert peak < 64 * 2 ** 20, peak
+        assert after.get("mp.window_bytes", 0) \
+            == before.get("mp.window_bytes", 0)
+        assert after["mp.window_blocks"] \
+            - before.get("mp.window_blocks", 0) == sum(map(blocks, sides))
 
 
 def test_counters_are_a_copy():

@@ -14,7 +14,7 @@ import pytest
 
 from repro.core import plan as plan_mod
 from repro.core.faults import FaultInjector, FaultPolicy
-from repro.core.zstats import compute_cross_stats_host
+from repro.core.zstats import _block_rows, compute_cross_stats_host
 from repro.serve import (AdmissionQueue, ProfileService, QueryRejected,
                          RoundLoop, ShardedCorpus)
 
@@ -50,19 +50,29 @@ def _reference_union(q, series, m):
     return best_d, best_s, best_i
 
 
-@pytest.mark.parametrize("dispatch_bytes", [None, 1])
+# a corpus series longer than one row block of the host window pass
+LONG_N = _block_rows(WINDOW) + WINDOW + 300
+
+
+@pytest.mark.parametrize("dispatch_bytes, series_n", [
+    pytest.param(None, 220, id="None"),
+    pytest.param(1, 220, id="1"),
+    pytest.param(None, LONG_N, id="None-long"),
+])
 def test_batched_service_matches_sequential_engine_bitwise(monkeypatch,
-                                                           dispatch_bytes):
+                                                           dispatch_bytes,
+                                                           series_n):
     """The headline equality: a batch of concurrent queries answered by the
     service is BITWISE-equal (distances, winning series, positions) to
     looping per-(query, series) sweeps and reducing on the host — also when
-    the memory bound splits the batch into one dispatch per query."""
+    the memory bound splits the batch into one dispatch per query, and when
+    a corpus series spans several row blocks of the window pass."""
     from repro.serve import frontend
 
     if dispatch_bytes is not None:
         monkeypatch.setattr(frontend, "DISPATCH_BYTES", dispatch_bytes)
     rng = np.random.default_rng(0)
-    series = _corpus_series(rng)
+    series = _corpus_series(rng, n=series_n)
     corpus = ShardedCorpus(series, WINDOW, n_shards=2)
     svc = ProfileService(corpus)
     queries = [rng.normal(size=150) for _ in range(4)]
