@@ -51,11 +51,11 @@ from repro import obs
 # lazily inside their executor branches so `import repro.core` stays light
 # for engine-only users.
 from repro.core.matrix_profile import (
-    AB_ROWSTREAM_MAX_ROWS, DEFAULT_BAND, DEFAULT_RESEED, ab_join_from_stats,
-    ab_join_nonnorm, ab_join_rowstream, ab_join_rowstream_topk,
-    ab_join_topk_from_stats, default_exclusion, nonnorm_profile_from_ts,
-    nonnorm_to_distance, profile_from_stats, profile_topk_from_stats,
-    tile_profile_from_stats,
+    AB_ROWSTREAM_MAX_ROWS, DEFAULT_BAND, DEFAULT_RESEED, TILE_EDGE,
+    ab_join_from_stats, ab_join_nonnorm, ab_join_rowstream,
+    ab_join_rowstream_topk, ab_join_topk_from_stats, ab_row_tile,
+    default_exclusion, nonnorm_profile_from_ts, nonnorm_to_distance,
+    profile_from_stats, profile_topk_from_stats, tile_profile_from_stats,
 )
 from repro.core.precision import DEFAULT_PRECISION, PrecisionSpec, as_precision
 from repro.core.result import HarvestSpec
@@ -329,6 +329,50 @@ def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
                      precision=prec)
 
 
+def _ab_spans(l_a: int, l_b: int, excl: int) -> list[tuple[int, int]]:
+    """Signed diagonal spans [s0, s1) an AB sweep launches: the whole space
+    at exclusion 0, else the parts below and above the excluded band."""
+    if excl == 0:
+        return [(-(l_a - 1), l_b)]
+    return ([(-(l_a - 1), -excl + 1)] if l_a - excl > 0 else []) \
+        + ([(excl, l_b)] if l_b - excl > 0 else [])
+
+
+def tile_cells(plan: SweepPlan) -> int:
+    """Cells of the distance matrix that `execute(plan, ...)`'s sweep
+    computes, masked cells included: whole tiles as the plan's geometry
+    lays them out (a band engine's (band, rows) tiles, the kernel's
+    (it, dt) grid, the bf16 route's square tiles, rowstream's full rows),
+    times the stack size of a batched plan. The cells an answer needs are
+    fewer; the gap is work spent on masked cells. Distributed plans run
+    through `round_executor`, whose rounds are not counted."""
+    if plan.backend == "distributed":
+        raise ValueError("distributed rounds are not counted")
+    ceil = lambda a, b: -(-a // b)                          # noqa: E731
+    band, l_a, excl = plan.band, plan.l_a, plan.exclusion
+    if plan.kind == "self":
+        if plan.backend == "kernel":
+            cells = (ceil(l_a, plan.it) * plan.it
+                     * ceil(max(l_a - excl, 1), plan.dt) * plan.dt)
+        elif plan.precision.reduced_stream:
+            nt = ceil(l_a, TILE_EDGE)
+            cells = nt * (nt + 1) // 2 * TILE_EDGE ** 2
+        else:           # the band engine at k = 1 and top-k, and nonnorm
+            cells = ceil(max(l_a - excl, 0), band) * band * l_a
+    elif plan.backend == "rowstream":
+        cells = l_a * plan.l_b
+    elif plan.backend == "kernel":
+        rows, cols = (plan.l_b, l_a) if plan.swap_ab else (l_a, plan.l_b)
+        cells = sum(ceil(rows, plan.it) * plan.it
+                    * ceil(max(s1 - s0, 1), plan.dt) * plan.dt
+                    for s0, s1 in _ab_spans(rows, cols, excl))
+    else:               # the band engine at k = 1 and top-k, and nonnorm
+        li = ab_row_tile(l_a, plan.l_b, band) if plan.clamp_rows else l_a
+        cells = sum(ceil(s1 - s0, band)
+                    for s0, s1 in _ab_spans(l_a, plan.l_b, excl)) * band * li
+    return cells * (plan.batch or 1)
+
+
 def stats_dtypes_for(plan: SweepPlan) -> dict:
     """The `(out_dtype, seed_dtype)` kwargs host stream prep needs under a
     plan — the one place the stream-emission dtype is decided.
@@ -442,15 +486,17 @@ def execute(plan: SweepPlan, stats) -> SweepResult:
     (`jax.tree.map(jnp.stack, ...)`). Distributed plans run round-by-round —
     build their SPMD step with `round_executor` instead.
 
-    The `mp.sweep` span covers the call; each path's harvest after the
-    launch is its child `mp.finish`, so `mp.sweep`'s self time is the
-    host's launch of the sweep."""
+    The `mp.sweep` span covers the call and names the plan's backend; each
+    path's harvest after the launch is its child `mp.finish`, so
+    `mp.sweep`'s self time is the host's launch of the sweep. The counter
+    `mp.tile_cells.<backend>` gains the sweep's `tile_cells(plan)`."""
     _check_stats(plan, stats)
     if plan.backend == "distributed":
         raise ValueError("distributed plans execute round-by-round: build "
                          "the SPMD round fn with round_executor(plan, mesh) "
                          "— AnytimeScheduler drives it")
-    with obs.span("mp.sweep"):
+    with obs.span("mp.sweep", backend=plan.backend):
+        obs.count(f"mp.tile_cells.{plan.backend}", tile_cells(plan))
         if plan.kind == "self":
             return _execute_self(plan, stats)
         return _execute_ab(plan, stats)

@@ -22,7 +22,8 @@ the time its child `mp.` spans on the same thread cover:
   mp.put             enqueuing the host -> device copies of streams and
                      seeds (the copies themselves run asynchronously)
   mp.sweep           `plan.execute` up to and including the sweep's launch;
-                     holds mp.finish
+                     holds mp.finish; its argument `backend` names the
+                     plan's backend (engine, rowstream, kernel)
   mp.finish          merges, distance conversion and result assembly after
                      the launch, and the lazy finish on first access
   mp.serve.step      one `ProfileService.step`
@@ -40,6 +41,11 @@ Counters are plain running sums, kept where the work happens:
   mp.window_blocks   row blocks of centered windows the streamed window
                      pass built (`zstats._window_pass`)
   mp.put_bytes       bytes the mp.put spans copy to the device
+  mp.tile_cells.<backend>
+                     distance-matrix cells the sweeps `plan.execute`
+                     launched computed, masked cells included
+                     (`plan.tile_cells`); the distributed rounds of
+                     `plan.round_executor` are not counted
 """
 
 from __future__ import annotations

@@ -29,6 +29,7 @@ def _series(n, seed=0, kind="walk"):
     (500, 8, "noise"),
     (400, 32, "sine"),
     (257, 10, "walk"),      # sizes not aligned to band
+    (2048, 64, "walk"),     # seismology-64k's shape, 32x shorter
 ])
 def test_engine_matches_bruteforce(n, m, kind):
     ts = _series(n, seed=n + m, kind=kind)

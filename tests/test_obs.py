@@ -65,6 +65,15 @@ def test_service_spans_carry_the_batch(spans):
     assert all(args.get("batch") == 1 for _, args in serve), serve
 
 
+def test_sweep_span_names_its_backend(spans):
+    """The kernel self-join, the AB join and the service batch: the planner's
+    route is an argument of each `mp.sweep`."""
+    backends = [args.get("backend") for events in spans.values()
+                for _, _, name, args in events if name == "mp.sweep"]
+    assert len(backends) == 3
+    assert set(backends) == {"kernel", "rowstream"}, backends
+
+
 def test_seeds_and_put_nest_inside_stats_on_one_thread(spans):
     n_stats = 0
     for events in spans.values():
