@@ -338,11 +338,39 @@ def _ab_spans(l_a: int, l_b: int, excl: int) -> list[tuple[int, int]]:
         + ([(excl, l_b)] if l_b - excl > 0 else [])
 
 
+def _kernel_launches(plan: SweepPlan) -> list[tuple[int, int, dict]]:
+    """(n_rows, n_diags, geometry) of each `natsa_mp.rowmax_profile_ab`
+    launch a kernel plan makes, as `kernels.ops` pads them: one for a
+    self-join, one per signed diagonal span of an AB join (short side on
+    rows)."""
+    ceil = lambda a, b: -(-a // b)                          # noqa: E731
+    it, dt, excl = plan.it, plan.dt, plan.exclusion
+    if plan.kind == "self":
+        l = plan.l_a
+        spans, rows, cols = [(excl, l)], l, l
+    else:
+        rows, cols = ((plan.l_b, plan.l_a) if plan.swap_ab
+                      else (plan.l_a, plan.l_b))
+        spans = _ab_spans(rows, cols, excl)
+    return [(ceil(rows, it), ceil(max(s1 - s0, 1), dt),
+             dict(it=it, dt=dt, k_start=s0, k_end=s1, l_i=rows, l_j=cols))
+            for s0, s1 in spans]
+
+
+def skipped_tiles(plan: SweepPlan) -> int:
+    """Grid steps of a kernel plan's launches that skip their tile, which
+    holds no valid cell (`natsa_mp.live_diag_tiles`)."""
+    from repro.kernels import natsa_mp
+
+    return sum(n_rows * n_diags - natsa_mp.live_tiles(n_rows, n_diags, **g)
+               for n_rows, n_diags, g in _kernel_launches(plan))
+
+
 def tile_cells(plan: SweepPlan) -> int:
     """Cells of the distance matrix that `execute(plan, ...)`'s sweep
     computes, masked cells included: whole tiles as the plan's geometry
-    lays them out (a band engine's (band, rows) tiles, the kernel's
-    (it, dt) grid, the bf16 route's square tiles, rowstream's full rows),
+    lays them out (a band engine's (band, rows) tiles, the kernel's live
+    (it, dt) tiles, the bf16 route's square tiles, rowstream's full rows),
     times the stack size of a batched plan. The cells an answer needs are
     fewer; the gap is work spent on masked cells. Distributed plans run
     through `round_executor`, whose rounds are not counted."""
@@ -350,22 +378,20 @@ def tile_cells(plan: SweepPlan) -> int:
         raise ValueError("distributed rounds are not counted")
     ceil = lambda a, b: -(-a // b)                          # noqa: E731
     band, l_a, excl = plan.band, plan.l_a, plan.exclusion
-    if plan.kind == "self":
-        if plan.backend == "kernel":
-            cells = (ceil(l_a, plan.it) * plan.it
-                     * ceil(max(l_a - excl, 1), plan.dt) * plan.dt)
-        elif plan.precision.reduced_stream:
+    if plan.backend == "kernel":
+        from repro.kernels import natsa_mp
+
+        cells = sum(natsa_mp.live_tiles(n_rows, n_diags, **g)
+                    for n_rows, n_diags, g in _kernel_launches(plan)) \
+            * plan.it * plan.dt
+    elif plan.kind == "self":
+        if plan.precision.reduced_stream:
             nt = ceil(l_a, TILE_EDGE)
             cells = nt * (nt + 1) // 2 * TILE_EDGE ** 2
         else:           # the band engine at k = 1 and top-k, and nonnorm
             cells = ceil(max(l_a - excl, 0), band) * band * l_a
     elif plan.backend == "rowstream":
         cells = l_a * plan.l_b
-    elif plan.backend == "kernel":
-        rows, cols = (plan.l_b, l_a) if plan.swap_ab else (l_a, plan.l_b)
-        cells = sum(ceil(rows, plan.it) * plan.it
-                    * ceil(max(s1 - s0, 1), plan.dt) * plan.dt
-                    for s0, s1 in _ab_spans(rows, cols, excl))
     else:               # the band engine at k = 1 and top-k, and nonnorm
         li = ab_row_tile(l_a, plan.l_b, band) if plan.clamp_rows else l_a
         cells = sum(ceil(s1 - s0, band)
@@ -489,7 +515,8 @@ def execute(plan: SweepPlan, stats) -> SweepResult:
     The `mp.sweep` span covers the call and names the plan's backend; each
     path's harvest after the launch is its child `mp.finish`, so
     `mp.sweep`'s self time is the host's launch of the sweep. The counter
-    `mp.tile_cells.<backend>` gains the sweep's `tile_cells(plan)`."""
+    `mp.tile_cells.<backend>` gains the sweep's `tile_cells(plan)`, and
+    `mp.skipped_tiles.kernel` a kernel plan's `skipped_tiles(plan)`."""
     _check_stats(plan, stats)
     if plan.backend == "distributed":
         raise ValueError("distributed plans execute round-by-round: build "
@@ -497,6 +524,8 @@ def execute(plan: SweepPlan, stats) -> SweepResult:
                          "— AnytimeScheduler drives it")
     with obs.span("mp.sweep", backend=plan.backend):
         obs.count(f"mp.tile_cells.{plan.backend}", tile_cells(plan))
+        if plan.backend == "kernel":
+            obs.count("mp.skipped_tiles.kernel", skipped_tiles(plan))
         if plan.kind == "self":
             return _execute_self(plan, stats)
         return _execute_ab(plan, stats)

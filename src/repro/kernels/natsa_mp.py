@@ -47,11 +47,25 @@ k_start = excl and jpad = 0 — its column harvest IS the lower triangle, so
 one launch yields the complete profile.
 
 Grid: (n_row_tiles, n_diag_tiles), diag innermost so the output row block is
-revisited consecutively (read-modify-max accumulation), while the covariance
-scratch row for each diag tile persists across the outer row loop. A column
-bank is revisited consecutively within one row tile and re-fetched when the
-row loop wraps the bank index back down (correct on the sequential TPU grid;
-the wrap costs one HBM round-trip per bank per row tile).
+revisited consecutively (the d == 0 step writes NEG/-1, then read-modify-max
+accumulation), while the covariance scratch row for each diag tile persists
+across the outer row loop. A column bank is revisited consecutively within
+one row tile and re-fetched when the row loop wraps the bank index back down
+(correct on the sequential TPU grid; the wrap costs one HBM round-trip per
+bank per row tile).
+
+Only LIVE grid steps do work. A tile is dead when none of its cells passes
+the geometric mask: it lies wholly past column l_j - 1 (the self-join's
+lower-right triangle, half its grid, and the AB suffix) or wholly before
+column 0 (the AB prefix over the zero prepad). `live_diag_tiles` gives each
+row tile's one run of live diag tiles. A dead step computes nothing, leaves
+the diagonal registers and the resident bank alone, and its j-side index
+maps are clamped to the nearest live tile of its row, so the pipeline sees
+an unchanged block index and issues no DMA. Skipping is exact: past the end,
+no later row tile has a live cell on that diag tile (columns only grow with
+the row), so its registers are never read again; before column 0, computing
+would add exact zeros from the prepad to the seeds; and a dead tile offers
+only NEG/-1, which the strict `>` accumulators never take.
 
 The j-side streams are DOUBLE-BUFFERED diagonal slabs, not whole-array VMEM
 residents: each of df_j/dg_j/invn_j is passed TWICE with (1, JB)-blocked
@@ -88,6 +102,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -146,6 +161,40 @@ def _reverse_sublanes(x):
     return x
 
 
+def live_diag_tiles(i, *, it: int, dt: int, k_start: int, k_end: int,
+                    l_i: int, l_j: int, n_diags: int, xp=np):
+    """(d_lo, d_end): row tile `i`'s live diag tiles are d_lo <= d < d_end
+    (none where d_end <= d_lo); every other tile of the row is dead.
+
+    Tile (i, d) keeps rows i0 + [0, ni) and diagonals k0 + [0, nd) under the
+    kernel's mask, with i0 = i*it, k0 = k_start + d*dt, ni = min(it, l_i -
+    i0), nd = min(dt, k_end - k0). Its cells' columns are the consecutive
+    run i0 + k0 + [0, ni + nd - 1), so it holds a valid cell iff ni, nd >= 1
+    and the run meets [0, l_j):
+      i0 + k0 < l_j                            (not past the end)
+      i0 + ni - 2 + min(k0 + dt, k_end) >= 0   (not before column 0)
+    The first bounds d from above, the second from below. One inequality
+    for the kernel and its index maps (`i` a traced scalar, xp=jnp) and for
+    the plan's counters (`i` an integer array, xp=np); every division below
+    has a non-negative numerator."""
+    i0 = i * it
+    ni = xp.minimum(it, l_i - i0)
+    d_lo = (xp.maximum(2 - i0 - ni - dt - k_start, 0) + dt - 1) // dt
+    d_end = xp.minimum((xp.maximum(l_j - i0 - k_start, 0) + dt - 1) // dt,
+                       min(n_diags, -(-max(k_end - k_start, 0) // dt)))
+    rows_ok = (ni >= 1) & (i0 + ni + k_end >= 2)
+    return d_lo, xp.where(rows_ok, d_end, 0)
+
+
+def live_tiles(n_rows: int, n_diags: int, **geometry) -> int:
+    """Live grid steps of one `rowmax_profile_ab` launch: the (n_rows,
+    n_diags) grid less the steps `live_diag_tiles` finds dead. `geometry`
+    is the launch's it, dt, k_start, k_end, l_i, l_j."""
+    d_lo, d_end = live_diag_tiles(np.arange(n_rows), n_diags=n_diags,
+                                  **geometry)
+    return int(np.maximum(d_end - d_lo, 0).sum())
+
+
 def _kernel(df_row, dg_row, invn_row, df_j0, dg_j0, invn_j0,
             df_j1, dg_j1, invn_j1, seeds_hbm, _colc_init, _coli_init,
             out_corr, out_idx, colc_hbm, coli_hbm,
@@ -154,9 +203,6 @@ def _kernel(df_row, dg_row, invn_row, df_j0, dg_j0, invn_j0,
             col_stride: int, n_rows: int, n_diags: int):
     i_idx = pl.program_id(0)
     d_idx = pl.program_id(1)
-    i0 = i_idx * it
-    k0 = k_start + d_idx * dt          # signed diagonal offset of this tile
-    s = i0 + k0 + jpad                 # flat start of this tile's window
 
     # seed every diagonal register once (one DMA of the (DT, n_diags) seed
     # table); no column bank is resident yet
@@ -165,137 +211,159 @@ def _kernel(df_row, dg_row, invn_row, df_j0, dg_j0, invn_j0,
         pltpu.sync_copy(seeds_hbm, carry)
         cur_bank[0] = -1
 
-    # swap the resident column bank when this tile's window moved to another
-    # one: write the old bank back, read the new one (see module docstring)
-    bank = s // col_stride
-
-    @pl.when(bank != cur_bank[0])
-    def _swap():
-        @pl.when(cur_bank[0] >= 0)
-        def _write_back():
-            old = cur_bank[0]
-            pltpu.sync_copy(bank_c, colc_hbm.at[old])
-            pltpu.sync_copy(bank_i, coli_hbm.at[old])
-
-        pltpu.sync_copy(colc_hbm.at[bank], bank_c)
-        pltpu.sync_copy(coli_hbm.at[bank], bank_i)
-        cur_bank[0] = bank
-
-    # this diag tile's registers: lane d of the lane-aligned (DT, 128) slab
-    c0 = pl.multiple_of((d_idx // LANE) * LANE, LANE)
-    slab = carry[:, pl.ds(c0, LANE)]
-    mine = jax.lax.broadcasted_iota(jnp.int32, (dt, LANE), 1) == d_idx % LANE
-    cov_in = jnp.max(jnp.where(mine, slab, -jnp.inf), axis=1, keepdims=True)
-
-    # reduced-dtype streams upcast at VREG distance — HBM moved the narrow
-    # bytes, the VPU computes wide
-    dfi = df_row[...].astype(jnp.float32)  # (1, IT)
-    dgi = dg_row[...].astype(jnp.float32)
-    invni = invn_row[...].astype(jnp.float32)
-
-    # j-side strips for each diagonal in the tile: diagonal dd covers
-    # [i0+k0+dd, i0+k0+dd+IT) — all of them inside the concatenated
-    # double-buffer window [p*JB, (p+2)*JB), p = s // JB, at lanes
-    # local + dd onward. Lane X + t of diagonal dd's row must hold window
-    # lane local + dd + t: a right rotate (pltpu.roll's direction) by
-    # X - local - dd, which lies in [0, 2*JB) for X = 2*JB - IT (no wrap).
-    # Diagonals sit on sublanes in REVERSED order (sublane r holds
-    # dd = DT-1-r), so the shift grows by one per sublane: one stride-1
-    # rotate. (A stride of 2*JB - 1, i.e. -1 modulo the width, would keep
-    # the natural order, but the chip's strided rotate does not wrap its
-    # stride that way.)
-    local = s - (s // jb) * jb
-    x0 = 2 * jb - it
-    rshift = x0 - local - (dt - 1)
-
-    def strips(r0, r1):
-        w = jnp.concatenate([r0[...], r1[...]], axis=1).astype(jnp.float32)
-        w = pltpu.roll(jnp.broadcast_to(w, (dt, 2 * jb)), rshift, 1,
-                       stride=1, stride_axis=0)
-        return w[:, x0:]                                         # (DT, IT)
-
-    dfj = strips(df_j0, df_j1)
-    dgj = strips(dg_j0, dg_j1)
-    invnj = strips(invn_j0, invn_j1)
-
-    ii = jax.lax.broadcasted_iota(jnp.int32, (dt, it), 1)          # row offset
-    dd = dt - 1 - jax.lax.broadcasted_iota(jnp.int32, (dt, it), 0)  # diag offset
-
-    delta = dfi * dgj + dfj * dgi                                  # (DT, IT)
-    cov = cov_in + _cumsum_lanes(delta, it)
-    last = jnp.max(jnp.where(ii == it - 1, cov, -jnp.inf), axis=1,
-                   keepdims=True)                                  # (DT, 1)
-    carry[:, pl.ds(c0, LANE)] = jnp.where(mine, last, slab)
-
-    corr = cov * invni * invnj
-
-    jpos = i0 + ii + k0 + dd                                       # signed j
-    ipos = i0 + ii
-    # invn < 0 is the missing-data sentinel (zstats.compute_stats_host):
-    # pairs touching a masked subsequence are excluded like out-of-range
-    # cells. The cumsum above is untouched — masked cells still carry the
-    # recurrence to later valid cells on the diagonal.
-    valid = ((jpos >= 0) & (jpos < l_j) & (ipos < l_i)
-             & (k0 + dd < k_end) & (invni >= 0) & (invnj >= 0))
-    corr = jnp.where(valid, corr, NEG)
-
-    # plain max + equality-recovered arg: cheaper than a variadic argmax
-    # reduce on both the interpret (XLA CPU) and Mosaic paths; the arg
-    # reduce runs in f32 (Mosaic has no integer reductions) — diagonal
-    # offsets are < DT, exactly representable
-    tile_best = jnp.max(corr, axis=0, keepdims=True)               # (1, IT)
-    best_d = jnp.max(
-        jnp.where(corr == tile_best, dd.astype(jnp.float32), -1.0),
-        axis=0, keepdims=True).astype(jnp.int32)
-    tile_idx = i0 + ii[:1] + k0 + best_d
-    tile_idx = jnp.where(tile_best > NEG, tile_idx, -1)
-
+    # the row block starts empty: its live steps read-modify-max into it,
+    # and a row tile may have no live step at all
     @pl.when(d_idx == 0)
     def _init():
-        out_corr[...] = tile_best
-        out_idx[...] = tile_idx
+        out_corr[...] = jnp.full(out_corr.shape, NEG, jnp.float32)
+        out_idx[...] = jnp.full(out_idx.shape, -1, jnp.int32)
 
-    @pl.when(d_idx != 0)
-    def _acc():
+    d_lo, d_end = live_diag_tiles(i_idx, it=it, dt=dt, k_start=k_start,
+                                  k_end=k_end, l_i=l_i, l_j=l_j,
+                                  n_diags=n_diags, xp=jnp)
+
+    @pl.when((d_idx >= d_lo) & (d_idx < d_end))
+    def _live():
+        i0 = i_idx * it
+        k0 = k_start + d_idx * dt      # signed diagonal offset of this tile
+        s = i0 + k0 + jpad             # flat start of this tile's window
+
+        # swap the resident column bank when this tile's window moved to
+        # another one: write the old bank back, read the new one (see
+        # module docstring)
+        bank = s // col_stride
+
+        @pl.when(bank != cur_bank[0])
+        def _swap():
+            @pl.when(cur_bank[0] >= 0)
+            def _write_back():
+                old = cur_bank[0]
+                pltpu.sync_copy(bank_c, colc_hbm.at[old])
+                pltpu.sync_copy(bank_i, coli_hbm.at[old])
+
+            pltpu.sync_copy(colc_hbm.at[bank], bank_c)
+            pltpu.sync_copy(coli_hbm.at[bank], bank_i)
+            cur_bank[0] = bank
+
+        # this diag tile's registers: lane d of the lane-aligned (DT, 128)
+        # slab
+        c0 = pl.multiple_of((d_idx // LANE) * LANE, LANE)
+        slab = carry[:, pl.ds(c0, LANE)]
+        mine = (jax.lax.broadcasted_iota(jnp.int32, (dt, LANE), 1)
+                == d_idx % LANE)
+        cov_in = jnp.max(jnp.where(mine, slab, -jnp.inf), axis=1,
+                         keepdims=True)
+
+        # reduced-dtype streams upcast at VREG distance — HBM moved the
+        # narrow bytes, the VPU computes wide
+        dfi = df_row[...].astype(jnp.float32)  # (1, IT)
+        dgi = dg_row[...].astype(jnp.float32)
+        invni = invn_row[...].astype(jnp.float32)
+
+        # j-side strips for each diagonal in the tile: diagonal dd covers
+        # [i0+k0+dd, i0+k0+dd+IT) — all of them inside the concatenated
+        # double-buffer window [p*JB, (p+2)*JB), p = s // JB, at lanes
+        # local + dd onward. Lane X + t of diagonal dd's row must hold
+        # window lane local + dd + t: a right rotate (pltpu.roll's
+        # direction) by X - local - dd, which lies in [0, 2*JB) for
+        # X = 2*JB - IT (no wrap). Diagonals sit on sublanes in REVERSED
+        # order (sublane r holds dd = DT-1-r), so the shift grows by one per
+        # sublane: one stride-1 rotate. (A stride of 2*JB - 1, i.e. -1
+        # modulo the width, would keep the natural order, but the chip's
+        # strided rotate does not wrap its stride that way.)
+        local = s - (s // jb) * jb
+        x0 = 2 * jb - it
+        rshift = x0 - local - (dt - 1)
+
+        def strips(r0, r1):
+            w = jnp.concatenate([r0[...], r1[...]],
+                                axis=1).astype(jnp.float32)
+            w = pltpu.roll(jnp.broadcast_to(w, (dt, 2 * jb)), rshift, 1,
+                           stride=1, stride_axis=0)
+            return w[:, x0:]                                     # (DT, IT)
+
+        dfj = strips(df_j0, df_j1)
+        dgj = strips(dg_j0, dg_j1)
+        invnj = strips(invn_j0, invn_j1)
+
+        ii = jax.lax.broadcasted_iota(jnp.int32, (dt, it), 1)   # row offset
+        dd = dt - 1 - jax.lax.broadcasted_iota(jnp.int32, (dt, it),
+                                               0)              # diag offset
+
+        delta = dfi * dgj + dfj * dgi                              # (DT, IT)
+        cov = cov_in + _cumsum_lanes(delta, it)
+        last = jnp.max(jnp.where(ii == it - 1, cov, -jnp.inf), axis=1,
+                       keepdims=True)                              # (DT, 1)
+        carry[:, pl.ds(c0, LANE)] = jnp.where(mine, last, slab)
+
+        corr = cov * invni * invnj
+
+        jpos = i0 + ii + k0 + dd                                   # signed j
+        ipos = i0 + ii
+        # invn < 0 is the missing-data sentinel
+        # (zstats.compute_stats_host): pairs touching a masked subsequence
+        # are excluded like out-of-range cells. The cumsum above is
+        # untouched — masked cells still carry the recurrence to later valid
+        # cells on the diagonal.
+        valid = ((jpos >= 0) & (jpos < l_j) & (ipos < l_i)
+                 & (k0 + dd < k_end) & (invni >= 0) & (invnj >= 0))
+        corr = jnp.where(valid, corr, NEG)
+
+        # plain max + equality-recovered arg: cheaper than a variadic argmax
+        # reduce on both the interpret (XLA CPU) and Mosaic paths; the arg
+        # reduce runs in f32 (Mosaic has no integer reductions) — diagonal
+        # offsets are < DT, exactly representable
+        tile_best = jnp.max(corr, axis=0, keepdims=True)           # (1, IT)
+        best_d = jnp.max(
+            jnp.where(corr == tile_best, dd.astype(jnp.float32), -1.0),
+            axis=0, keepdims=True).astype(jnp.int32)
+        tile_idx = i0 + ii[:1] + k0 + best_d
+        tile_idx = jnp.where(tile_best > NEG, tile_idx, -1)
+
         prev = out_corr[...]
         take = tile_best > prev
         out_corr[...] = jnp.where(take, tile_best, prev)
         out_idx[...] = jnp.where(take, tile_idx, out_idx[...])
 
-    # -- column harvest of the SAME tile --------------------------------------
-    # the tile covers columns j in [i0+k0, i0+k0+IT+DT); the best value ending
-    # at local column t is max_dd corr[dd, t - dd] — row dd right-rotated by
-    # dd (back in natural sublane order, one strided rotate of the
-    # NEG-padded tile), then one read-modify-max into the resident bank
-    wr = rmw_width(it, dt)
-    shifted = pltpu.roll(
-        jnp.concatenate([_reverse_sublanes(corr),
-                         jnp.full((dt, wr - it), NEG, jnp.float32)],
-                        axis=1), 0, 1, stride=1, stride_axis=0)    # (DT, WR)
-    col_best = jnp.max(shifted, axis=0, keepdims=True)             # (1, WR)
-    ddw = jax.lax.broadcasted_iota(jnp.int32, (dt, wr), 0).astype(jnp.float32)
-    col_d = jnp.max(jnp.where(shifted == col_best, ddw, -1.0), axis=0,
-                    keepdims=True).astype(jnp.int32)
-    col_i = i0 + jax.lax.broadcasted_iota(jnp.int32, (1, wr), 1) - col_d
-    col_i = jnp.where(col_best > NEG, col_i, -1)
+        # -- column harvest of the SAME tile ---------------------------------
+        # the tile covers columns j in [i0+k0, i0+k0+IT+DT); the best value
+        # ending at local column t is max_dd corr[dd, t - dd] — row dd
+        # right-rotated by dd (back in natural sublane order, one strided
+        # rotate of the NEG-padded tile), then one read-modify-max into the
+        # resident bank
+        wr = rmw_width(it, dt)
+        shifted = pltpu.roll(
+            jnp.concatenate([_reverse_sublanes(corr),
+                             jnp.full((dt, wr - it), NEG, jnp.float32)],
+                            axis=1), 0, 1, stride=1, stride_axis=0)  # (DT, WR)
+        col_best = jnp.max(shifted, axis=0, keepdims=True)           # (1, WR)
+        ddw = jax.lax.broadcasted_iota(jnp.int32, (dt, wr),
+                                       0).astype(jnp.float32)
+        col_d = jnp.max(jnp.where(shifted == col_best, ddw, -1.0), axis=0,
+                        keepdims=True).astype(jnp.int32)
+        col_i = i0 + jax.lax.broadcasted_iota(jnp.int32, (1, wr), 1) - col_d
+        col_i = jnp.where(col_best > NEG, col_i, -1)
 
-    # the window starts at `local` inside the bank: read-modify-max the
-    # lane-aligned WR-wide slab holding it, with the candidates right-rotated
-    # into place (their NEG/-1 tail wraps around as max-merge no-ops)
-    local = s - bank * col_stride
-    a0 = pl.multiple_of((local // LANE) * LANE, LANE)
-    cand_c = pltpu.roll(col_best, local - a0, 1)
-    cand_i = pltpu.roll(col_i, local - a0, 1)
-    prev_c = bank_c[:, pl.ds(a0, wr)]
-    prev_i = bank_i[:, pl.ds(a0, wr)]
-    take_c = cand_c > prev_c
-    bank_c[:, pl.ds(a0, wr)] = jnp.where(take_c, cand_c, prev_c)
-    bank_i[:, pl.ds(a0, wr)] = jnp.where(take_c, cand_i, prev_i)
+        # the window starts at `local` inside the bank: read-modify-max the
+        # lane-aligned WR-wide slab holding it, with the candidates
+        # right-rotated into place (their NEG/-1 tail wraps around as
+        # max-merge no-ops)
+        local = s - bank * col_stride
+        a0 = pl.multiple_of((local // LANE) * LANE, LANE)
+        cand_c = pltpu.roll(col_best, local - a0, 1)
+        cand_i = pltpu.roll(col_i, local - a0, 1)
+        prev_c = bank_c[:, pl.ds(a0, wr)]
+        prev_i = bank_i[:, pl.ds(a0, wr)]
+        take_c = cand_c > prev_c
+        bank_c[:, pl.ds(a0, wr)] = jnp.where(take_c, cand_c, prev_c)
+        bank_i[:, pl.ds(a0, wr)] = jnp.where(take_c, cand_i, prev_i)
 
-    @pl.when((i_idx == n_rows - 1) & (d_idx == n_diags - 1))
+    # write back the resident bank, wherever the last live step left it
+    @pl.when((i_idx == n_rows - 1) & (d_idx == n_diags - 1)
+             & (cur_bank[0] >= 0))
     def _finish():
-        pltpu.sync_copy(bank_c, colc_hbm.at[bank])
-        pltpu.sync_copy(bank_i, coli_hbm.at[bank])
+        pltpu.sync_copy(bank_c, colc_hbm.at[cur_bank[0]])
+        pltpu.sync_copy(bank_i, coli_hbm.at[cur_bank[0]])
 
 
 def col_bank_layout(col_len: int, it: int, dt: int,
@@ -397,11 +465,20 @@ def rowmax_profile_ab(df_i, dg_i, invn_i, df_j, dg_j, invn_j, cov0, *,
     # legal for Mosaic because the block's sublane dim is the array's own
     grid = (n_rows, n_diags)
     row_spec = pl.BlockSpec((1, it), lambda i, d: (0, i))
-    j_spec0 = pl.BlockSpec(
-        (1, jb), lambda i, d: (0, (i * it + k_start + d * dt + jpad) // jb))
-    j_spec1 = pl.BlockSpec(
-        (1, jb),
-        lambda i, d: (0, (i * it + k_start + d * dt + jpad) // jb + 1))
+    geometry = dict(it=it, dt=dt, k_start=k_start, k_end=k_end, l_i=l_i,
+                    l_j=l_j, n_diags=n_diags)
+
+    def j_block_of(i, d):
+        # a dead step asks for the block of the nearest live step of its row
+        # (of one fixed tile if the row has none): an unchanged block index,
+        # so the pipeline fetches nothing for it
+        d_lo, d_end = live_diag_tiles(i, **geometry, xp=jnp)
+        d = jnp.maximum(jnp.minimum(d, d_end - 1),
+                        jnp.minimum(d_lo, n_diags - 1))
+        return (i * it + k_start + d * dt + jpad) // jb
+
+    j_spec0 = pl.BlockSpec((1, jb), lambda i, d: (0, j_block_of(i, d)))
+    j_spec1 = pl.BlockSpec((1, jb), lambda i, d: (0, j_block_of(i, d) + 1))
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
 
     # seed table: diagonal tile d's DT seeds are lane d, in the kernel's

@@ -44,8 +44,14 @@ Counters are plain running sums, kept where the work happens:
   mp.tile_cells.<backend>
                      distance-matrix cells the sweeps `plan.execute`
                      launched computed, masked cells included
-                     (`plan.tile_cells`); the distributed rounds of
+                     (`plan.tile_cells`; the kernel computes only its live
+                     tiles); the distributed rounds of
                      `plan.round_executor` are not counted
+  mp.skipped_tiles.kernel
+                     grid steps of the kernel launches `plan.execute` made
+                     that skipped their tile, which holds no valid cell
+                     (`plan.skipped_tiles`); skipped / (skipped + live
+                     tiles) is how often the skip engages
 """
 
 from __future__ import annotations

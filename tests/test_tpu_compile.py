@@ -5,7 +5,8 @@ compiler is what refuses unaligned slices, unsupported primitives and
 oversized VMEM. These tests run that compiler for a DESCRIBED v5e (no chip
 needed): both kernel entries with interpret=False, at seismology-64k and
 power-512k (`configs/natsa.py`) plus a long AB geometry, asserting the
-program holds the Mosaic custom call and fits one chip's memory.
+program holds the Mosaic custom call and fits one chip's memory. One test
+runs the compiled kernel itself, and only on a TPU.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and a test-collection-time load would
@@ -115,3 +116,48 @@ def test_ab_join_kernel_compiles_for_v5e(one_chip, workload, n_a):
 
     rows, cols = f32(n_rows * it), f32(jlen)
     _compile(fn, (rows, rows, rows, cols, cols, cols, f32(n_diags * dt)))
+
+
+@pytest.mark.parametrize("case", ["self", "ab", "ab-excluded"])
+def test_compiled_kernel_with_dead_tiles_matches_engine_on_chip(case):
+    """On a TPU, the compiled kernel at the default 1024 x 64 tiles, at
+    shapes whose grids hold dead tiles, against the band engine: a banked
+    self-join with l = 8*1024 + 1 (its whole last row tile dead), an AB
+    join with prefix and suffix dead tiles, and an excluded AB (two
+    launches). Skipped off-TPU, where the interpreter runs the other
+    tests; `tests/conftest.py` pins the CPU unless `JAX_PLATFORMS` is set,
+    so on the chip run it with `JAX_PLATFORMS=tpu`."""
+    import numpy as np
+
+    from repro.core import plan as plan_mod
+    from repro.core.matrix_profile import ab_join, matrix_profile
+    from repro.core.zstats import dist_to_corr
+
+    if jax.default_backend() != "tpu":
+        pytest.skip("runs the compiled kernel: needs a TPU")
+    m = 128
+    rng = np.random.default_rng(2147483901)
+    a = np.cumsum(rng.normal(size=8 * 1024 + m))
+    b = np.cumsum(rng.normal(size=3000))
+    if case == "self":
+        got = ops.natsa_matrix_profile(a, m)
+        want = matrix_profile(a, m)
+        plan = plan_mod.plan_sweep(m, a.size - m + 1, backend="kernel")
+    elif case == "ab":
+        got = ops.natsa_ab_join(b, a, m)
+        want = ab_join(b, a, m)
+        plan = plan_mod.plan_sweep(m, b.size - m + 1, a.size - m + 1,
+                                   backend="kernel")
+    else:
+        excl = default_exclusion(m)
+        got = ops.natsa_ab_join(a, a, m, exclusion=excl)
+        want = matrix_profile(a, m, exclusion=excl)
+        plan = plan_mod.plan_sweep(m, a.size - m + 1, a.size - m + 1,
+                                   exclusion=excl, backend="kernel")
+    assert plan_mod.skipped_tiles(plan) > 0
+    cg, cw = (np.asarray(dist_to_corr(jnp.asarray(r.p), m))
+              for r in (got, want))
+    np.testing.assert_allclose(cg, cw, atol=5e-4)
+    # argmax ties can differ only where correlations are ~equal
+    mism = np.asarray(got.i) != np.asarray(want.i)
+    assert np.abs(cg[mism] - cw[mism]).max(initial=0) < 5e-4
