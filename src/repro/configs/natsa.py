@@ -1,6 +1,6 @@
 """Paper workloads: NATSA/ICCD'20 evaluates matrix profile on series of
-2^16..2^19 samples with windows in the hundreds. These drive benchmarks/
-and examples/; reduced sizes keep the CPU container tractable."""
+2^16..2^19 samples with windows in the hundreds. `chip_smoke.py` and the
+chip compile tests (`tests/test_tpu_compile.py`) run these sizes."""
 import dataclasses
 
 
@@ -16,10 +16,4 @@ PAPER_WORKLOADS = (
     NatsaWorkload("epilepsy-128k", 131072, 128),
     NatsaWorkload("ecg-256k", 262144, 512),
     NatsaWorkload("power-512k", 524288, 1024),
-)
-
-BENCH_WORKLOADS = (
-    NatsaWorkload("bench-4k", 4096, 64),
-    NatsaWorkload("bench-8k", 8192, 128),
-    NatsaWorkload("bench-16k", 16384, 128),
 )

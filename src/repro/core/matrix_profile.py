@@ -638,9 +638,8 @@ def tile_profile_from_stats(stats: ZStats, exclusion: int, *,
         the reseed machinery (`reseed_every` does not apply here);
       * the streamed traffic is the (l, m) centered-window matrix in the
         stream dtype — half the f32 bytes at bf16, which is the entire
-        NATSA thesis applied at the dtype level;
-      * measured ~2.9x the f32 band engine on the n = 16384 CI sweep (the
-        `mp_engine_bf16_n16384` bench row gates >= 1.5x).
+        NATSA thesis applied at the dtype level. No benchmark cell runs a
+        16-bit stream yet, so its speed on the chip is not measured.
 
     Harvests both sides of each upper-triangle (r, c) tile pair — the row
     max is the RIGHT profile, the column max the LEFT — merged into running
@@ -834,8 +833,7 @@ def _unskew(w: jax.Array, rows: int, li: int) -> jax.Array:
     return jnp.pad(p, (0, rows)).reshape(rows, W + 1)[:, :li]
 
 
-def _ab_padded_streams(cross: CrossStats, band: int, li: int,
-                       clamp_rows: bool = True):
+def _ab_padded_streams(cross: CrossStats, band: int, li: int):
     """Zero-pad both series' streams so every row slice (offset i0) and every
     j-side window slice (offset i0 + k0 + pad_left, width li + band) is in
     bounds for any diagonal a chunk scan can visit, including overshooting
@@ -843,9 +841,8 @@ def _ab_padded_streams(cross: CrossStats, band: int, li: int,
     reads are additionally masked to NEG before any harvest.
 
     Returns (pad_left, streams...). With the row clamp, i0 + k0 is at least
-    1 - band, so a `band`-wide left pad suffices; the unclamped A/B path
-    pins i0 = 0 and its window start k0 reaches -(l_a - 1)."""
-    pad_left = band if clamp_rows else band + cross.l_a - 1
+    1 - band, so a `band`-wide left pad suffices."""
+    pad_left = band
     pa = lambda x: jnp.pad(x, (0, li))                      # noqa: E731
     pb = lambda x: jnp.pad(x, (pad_left, li + 2 * band))    # noqa: E731
     sa, sb = cross.a, cross.b
@@ -867,7 +864,7 @@ def ab_reseed(l_a: int, l_b: int, reseed_every: int | None) -> int | None:
 def _band_corr_ab(cross: CrossStats, k0, band: int, *,
                   k_hi=None, reseed_every: int | None = None,
                   wa: jax.Array | None = None,
-                  wb: jax.Array | None = None, clamp_rows: bool = True,
+                  wb: jax.Array | None = None,
                   padded=None, accum_dtype=jnp.float32
                   ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The (D, li) correlation tile of signed diagonals [k0, k0+band) of the
@@ -879,11 +876,10 @@ def _band_corr_ab(cross: CrossStats, k0, band: int, *,
     acc = jnp.dtype(accum_dtype)
     sa, sb = cross.a, cross.b
     la, lb = sa.n_subsequences, sb.n_subsequences
-    li = ab_row_tile(la, lb, band) if clamp_rows else la
-    i0 = (jnp.maximum(0, -(k0 + band - 1)).astype(jnp.int32)
-          if clamp_rows else jnp.int32(0))
+    li = ab_row_tile(la, lb, band)
+    i0 = jnp.maximum(0, -(k0 + band - 1)).astype(jnp.int32)
     if padded is None:
-        padded = _ab_padded_streams(cross, band, li, clamp_rows)
+        padded = _ab_padded_streams(cross, band, li)
     pad_left, dfa_p, dga_p, invna_p, dfb_p, dgb_p, invnb_p = padded
 
     ks = k0 + jnp.arange(band)                     # (D,) signed
@@ -953,8 +949,7 @@ def band_rowmax_ab(cross: CrossStats, k0, band: int, *,
                    k_hi=None, reseed_every: int | None = None,
                    wa: jax.Array | None = None,
                    wb: jax.Array | None = None, harvest_cols: bool = True,
-                   clamp_rows: bool = True, padded=None,
-                   accum_dtype=jnp.float32
+                   padded=None, accum_dtype=jnp.float32
                    ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                               jax.Array]:
     """Two-sided harvest of A vs B over signed diagonals [k0, k0+band).
@@ -970,15 +965,12 @@ def band_rowmax_ab(cross: CrossStats, k0, band: int, *,
     `k0` may be traced and NEGATIVE; `band` is static. `k_hi` additionally
     masks diagonals >= k_hi (chunk ends that are not band-aligned).
     `harvest_cols=False` skips the column window when B's profile is not
-    wanted (win, win_i come back None); `clamp_rows=False` forces i0 = 0 and
-    li = l_a — the pre-clamp full-height sweep, kept for A/B tests and
-    benches. Stream loads are dynamic slices + static skews (`_unskew`), not
-    2-D gathers.
+    wanted (win, win_i come back None). Stream loads are dynamic slices +
+    static skews (`_unskew`), not 2-D gathers.
     """
     corr, i, i0 = _band_corr_ab(cross, k0, band, k_hi=k_hi,
                                 reseed_every=reseed_every, wa=wa, wb=wb,
-                                clamp_rows=clamp_rows, padded=padded,
-                                accum_dtype=accum_dtype)
+                                padded=padded, accum_dtype=accum_dtype)
     corr_best, d_win = _row_harvest(corr)
     idx_best = (i + k0 + d_win).astype(jnp.int32)
     idx_best = jnp.where(corr_best > NEG, idx_best, -1)
@@ -993,16 +985,14 @@ def band_topk_ab(cross: CrossStats, k0, band: int, k: int, *,
                  k_hi=None, reseed_every: int | None = None,
                  wa: jax.Array | None = None,
                  wb: jax.Array | None = None, harvest_cols: bool = True,
-                 clamp_rows: bool = True, padded=None,
-                 accum_dtype=jnp.float32
+                 padded=None, accum_dtype=jnp.float32
                  ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                             jax.Array]:
     """`band_rowmax_ab` widened to exact top-k — ((li, k) row window,
     row_idx, (li+band, k) col window, win_i, i0) off the same tile."""
     corr, i, i0 = _band_corr_ab(cross, k0, band, k_hi=k_hi,
                                 reseed_every=reseed_every, wa=wa, wb=wb,
-                                clamp_rows=clamp_rows, padded=padded,
-                                accum_dtype=accum_dtype)
+                                padded=padded, accum_dtype=accum_dtype)
     vals, d = _topk_rows(corr, k)
     idx = (i[:, None] + k0 + d).astype(jnp.int32)
     idx = jnp.where(vals > NEG, idx, -1)
@@ -1016,8 +1006,7 @@ def band_topk_ab(cross: CrossStats, k0, band: int, k: int, *,
 def chunk_rowmax_ab(cross: CrossStats, k0, width_static: int, band: int,
                     reseed_every: int | None = DEFAULT_RESEED,
                     k_hi=None, two_sided: bool = True,
-                    clamp_rows: bool = True, col_tile: int | None = None,
-                    accum_dtype=jnp.float32
+                    col_tile: int | None = None, accum_dtype=jnp.float32
                     ) -> tuple[ProfileState, ProfileState | None]:
     """Two-sided states over signed diagonals [k0, k0+width), band-scanned.
 
@@ -1038,8 +1027,8 @@ def chunk_rowmax_ab(cross: CrossStats, k0, width_static: int, band: int,
     reseed_every = ab_reseed(la, lb, reseed_every)
     wa = centered_windows(cross.a) if reseed_every is not None else None
     wb = centered_windows(cross.b) if reseed_every is not None else None
-    li = ab_row_tile(la, lb, band) if clamp_rows else la
-    padded = _ab_padded_streams(cross, band, li, clamp_rows)
+    li = ab_row_tile(la, lb, band)
+    padded = _ab_padded_streams(cross, band, li)
     pad_l = la - 1                 # most negative valid diagonal start
     pad_r = li + 2 * band          # last window + overshooting bands
 
@@ -1050,7 +1039,6 @@ def chunk_rowmax_ab(cross: CrossStats, k0, width_static: int, band: int,
                                              reseed_every=reseed_every,
                                              wa=wa, wb=wb,
                                              harvest_cols=two_sided,
-                                             clamp_rows=clamp_rows,
                                              padded=padded,
                                              accum_dtype=acc)
         rows = rows.merge_window(ra, ia, i0)
@@ -1072,12 +1060,12 @@ def chunk_rowmax_ab(cross: CrossStats, k0, width_static: int, band: int,
             col.to_profile(pad_l, lb) if two_sided else None)
 
 
-@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5, 6),
+@partial(jax.jit, static_argnums=(1, 2, 3, 4, 5),
          static_argnames=("accum_dtype",))
 def ab_join_from_stats(cross: CrossStats, exclusion: int = 0,
                        band: int = DEFAULT_BAND,
                        reseed_every: int | None = DEFAULT_RESEED,
-                       two_sided: bool = True, clamp_rows: bool = True,
+                       two_sided: bool = True,
                        col_tile: int | None = None, *,
                        accum_dtype: str = "float32"
                        ) -> tuple[ProfileState, ProfileState | None]:
@@ -1090,8 +1078,7 @@ def ab_join_from_stats(cross: CrossStats, exclusion: int = 0,
     two-span split visited it twice). A's profile is exact from the row
     harvest alone (the signed span covers every cell of each row), so
     `two_sided=False` skips the column harvest and returns state_b=None —
-    the cheap path when B's profile is not wanted. `clamp_rows=False`
-    restores the pre-clamp full-height sweep (A/B testing only).
+    the cheap path when B's profile is not wanted.
     """
     acc = jnp.dtype(accum_dtype)
     la, lb = cross.l_a, cross.l_b
@@ -1108,7 +1095,7 @@ def ab_join_from_stats(cross: CrossStats, exclusion: int = 0,
     if excl == 0:
         merge(*chunk_rowmax_ab(cross, jnp.int32(-(la - 1)), la - 1 + lb,
                                band, reseed_every, k_hi=lb,
-                               two_sided=two_sided, clamp_rows=clamp_rows,
+                               two_sided=two_sided,
                                col_tile=col_tile, accum_dtype=acc))
         return state_a, state_b
     neg_width = la - excl          # diagonals [-(l_a-1), -excl]
@@ -1116,13 +1103,12 @@ def ab_join_from_stats(cross: CrossStats, exclusion: int = 0,
     if neg_width > 0:
         merge(*chunk_rowmax_ab(cross, jnp.int32(-(la - 1)), neg_width, band,
                                reseed_every, k_hi=-excl + 1,
-                               two_sided=two_sided, clamp_rows=clamp_rows,
+                               two_sided=two_sided,
                                col_tile=col_tile, accum_dtype=acc))
     if pos_width > 0:
         merge(*chunk_rowmax_ab(cross, jnp.int32(excl), pos_width, band,
                                reseed_every, k_hi=lb, two_sided=two_sided,
-                               clamp_rows=clamp_rows, col_tile=col_tile,
-                               accum_dtype=acc))
+                               col_tile=col_tile, accum_dtype=acc))
     return state_a, state_b
 
 
@@ -1398,8 +1384,7 @@ def ab_join(ts_a, ts_b, window: int, *, exclusion: int | None = None,
     swept with its SHORT side on rows (`swap_ab`) via `ab_join_rowstream`
     whenever that side fits AB_ROWSTREAM_MAX_ROWS; huge near-square joins
     and nonnorm sweeps take the band-diagonal engine, whose tiles are
-    row-clamped to the rectangle. The pre-clamp full-height sweep survives
-    only as an A/B-comparison plan (`plan_sweep(..., clamp_rows=False)`).
+    row-clamped to the rectangle.
     """
     from repro.core import plan as plan_mod
     from repro.core.result import build_result
@@ -1589,23 +1574,20 @@ def nonnorm_profile_from_ts(ts: jax.Array, window: int, exclusion: int,
 
 def band_rowmin_nonnorm_ab(ts_a: jax.Array, ts_b: jax.Array, d20s: jax.Array,
                            window: int, k0, band: int, k_hi=None,
-                           harvest_cols: bool = True,
-                           clamp_rows: bool = True, padded=None):
+                           harvest_cols: bool = True, padded=None):
     """Non-normalized squared-Euclidean AB harvest over signed diagonals
     [k0, k0+band). `d20s` are the seed distances at each diagonal's start
     cell (index k + l_a - 1). Returns (neg_d2 (li,), idx, win (li+band,),
     win_i, i0) — A's row-profile WINDOW over rows [i0, i0+li) and B's
     column-profile window of the same row-clamped tile (win/win_i None with
-    `harvest_cols=False`); li is `ab_row_tile(l_a, l_b, band)` unless
-    `clamp_rows=False` pins i0 = 0, li = l_a."""
+    `harvest_cols=False`); li is `ab_row_tile(l_a, l_b, band)`."""
     m = int(window)
     na, nb = ts_a.shape[0], ts_b.shape[0]
     la, lb = na - m + 1, nb - m + 1
-    li = ab_row_tile(la, lb, band) if clamp_rows else la
-    i0 = (jnp.maximum(0, -(k0 + band - 1)).astype(jnp.int32)
-          if clamp_rows else jnp.int32(0))
+    li = ab_row_tile(la, lb, band)
+    i0 = jnp.maximum(0, -(k0 + band - 1)).astype(jnp.int32)
     if padded is None:
-        padded = _nonnorm_padded_series(ts_a, ts_b, band, li, clamp_rows)
+        padded = _nonnorm_padded_series(ts_a, ts_b, band, li)
     pad_left, tsa_p, tsb_p = padded
 
     ks = k0 + jnp.arange(band)                          # (D,) signed
@@ -1648,34 +1630,29 @@ def band_rowmin_nonnorm_ab(ts_a: jax.Array, ts_b: jax.Array, d20s: jax.Array,
     return neg_best.astype(jnp.float32), idx, win, win_i, i0
 
 
-def _nonnorm_padded_series(ts_a, ts_b, band: int, li: int,
-                           clamp_rows: bool = True):
+def _nonnorm_padded_series(ts_a, ts_b, band: int, li: int):
     """Pad raw series so the nonnorm band's slices (rows at i0 - 1, strips at
     i0 + k0 - 1 .. + m - 1 + li + band) stay in bounds; pad reads are masked
-    before any harvest. Returns (pad_left, A_padded, B_padded); the
-    unclamped path needs the extra l_a - 1 of left slack (see
-    `_ab_padded_streams`)."""
-    la = ts_a.shape[0]            # >= l_a, safe left-slack bound
-    pad_left = band if clamp_rows else band + la - 1
+    before any harvest. Returns (pad_left, A_padded, B_padded); as in
+    `_ab_padded_streams`, the row clamp keeps i0 + k0 >= 1 - band."""
+    pad_left = band
     return (pad_left, jnp.pad(ts_a, (1, li + 1)),
             jnp.pad(ts_b, (pad_left + 1, li + 2 * band + 1)))
 
 
-@partial(jax.jit, static_argnums=(2, 3, 4),
-         static_argnames=("two_sided", "clamp_rows"))
+@partial(jax.jit, static_argnums=(2, 3, 4), static_argnames=("two_sided",))
 def ab_join_nonnorm(ts_a: jax.Array, ts_b: jax.Array, window: int,
                     exclusion: int = 0, band: int = DEFAULT_BAND, *,
-                    two_sided: bool = True, clamp_rows: bool = True):
+                    two_sided: bool = True):
     """Exact non-normalized AB join -> (dist_a (l_a,), idx_a, dist_b (l_b,),
     idx_b) — both sides from one signed-diagonal sweep (dist_b/idx_b are
     None with `two_sided=False`, which skips the column harvest; A's
     profile needs only the row side).
 
     Same signed-diagonal streaming as the z-normalized AB engine — including
-    the row clamp (`clamp_rows=False` restores the full-height sweep) — with
-    the raw-distance recurrence of `band_rowmin_nonnorm`. With
-    exclusion == 0 the whole signed space is one span (diagonal k = 0
-    evaluated once).
+    the row clamp — with the raw-distance recurrence of
+    `band_rowmin_nonnorm`. With exclusion == 0 the whole signed space is one
+    span (diagonal k = 0 evaluated once).
     """
     from repro.core.zstats import sliding_dot
 
@@ -1704,8 +1681,8 @@ def ab_join_nonnorm(ts_a: jax.Array, ts_b: jax.Array, window: int,
     d20s = jnp.concatenate([d20_neg[::-1], d20_pos])
 
     pad_l = la - 1
-    li = ab_row_tile(la, lb, band) if clamp_rows else la
-    padded = _nonnorm_padded_series(ts_a, ts_b, band, li, clamp_rows)
+    li = ab_row_tile(la, lb, band)
+    padded = _nonnorm_padded_series(ts_a, ts_b, band, li)
 
     def span(k_lo, width, k_hi):
         n_bands = -(-width // band)
@@ -1715,7 +1692,7 @@ def ab_join_nonnorm(ts_a: jax.Array, ts_b: jax.Array, window: int,
             start = k_lo + b * band
             ra, ia, win, wi, i0 = band_rowmin_nonnorm_ab(
                 ts_a, ts_b, d20s, m, start, band, k_hi=k_hi,
-                harvest_cols=two_sided, clamp_rows=clamp_rows, padded=padded)
+                harvest_cols=two_sided, padded=padded)
             rows = rows.merge_window(ra, ia, i0)
             if two_sided:
                 col = col.merge_window(win, wi, start + i0 + pad_l)

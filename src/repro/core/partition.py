@@ -246,16 +246,3 @@ def balance_badness(l: int, ranges: list[tuple[int, int]]) -> float:
     if w.size == 0:
         return 1.0
     return float(w.max() / w.mean())
-
-
-def balance_badness_ab(l_a: int, l_b: int,
-                       ranges: list[tuple[int, int]],
-                       band: int = 1) -> float:
-    """Straggler metric over signed AB ranges (see `balance_badness`).
-    `band` > 1 scores under the row-clamped engine cost model."""
-    w = np.array([range_work_ab(l_a, l_b, r, band=band) for r in ranges],
-                 dtype=np.float64)
-    w = w[w > 0]
-    if w.size == 0:
-        return 1.0
-    return float(w.max() / w.mean())

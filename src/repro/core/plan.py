@@ -94,7 +94,6 @@ class SweepPlan:
     swap_ab: bool = False           # executor sweeps B-vs-A, un-swaps outputs
     # -- tiling ------------------------------------------------------------
     band: int = DEFAULT_BAND        # diagonals per band tile
-    clamp_rows: bool = True         # row-clamp AB band tiles to the rectangle
     col_tile: int | None = None     # column-accumulator bank width policy
     n_bands: int | None = None      # distributed: static bands per chunk
     it: int = DEFAULT_IT            # kernel row-tile height
@@ -180,7 +179,7 @@ def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
                exclusion: int | None = None, normalize: bool = True,
                harvest: str | HarvestSpec = "merged", k: int = 1,
                backend: str | None = None,
-               band: int = DEFAULT_BAND, clamp_rows: bool = True,
+               band: int = DEFAULT_BAND,
                col_tile: int | None = None,
                reseed_every: int | None = DEFAULT_RESEED,
                it: int = DEFAULT_IT, dt: int = DEFAULT_DT,
@@ -261,7 +260,7 @@ def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
                          "lower k")
 
     if backend is None:
-        if kind == "ab" and normalize and batch is None and clamp_rows \
+        if kind == "ab" and normalize and batch is None \
                 and min(l_a, l_b) <= AB_ROWSTREAM_MAX_ROWS \
                 and spec.k <= min(l_a, l_b):
             backend = "rowstream"
@@ -290,9 +289,6 @@ def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
     if topk and col_tile is not None:
         raise ValueError("the banked column accumulator (col_tile) is "
                          "k=1-only; top-k plans accumulate flat")
-    if topk and not clamp_rows:
-        raise ValueError("clamp_rows=False is the k=1 A/B-comparison sweep; "
-                         "top-k plans always row-clamp")
     if prec.reduced_stream and not normalize:
         raise ValueError("16-bit streams are z-normalized only: raw squared "
                          "distances have no [-1, 1] bound, so reduced "
@@ -322,7 +318,7 @@ def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
                      l_b=None if l_b is None else int(l_b),
                      window=m, exclusion=excl,
                      normalize=normalize, harvest=spec, swap_ab=swap_ab,
-                     band=int(band), clamp_rows=clamp_rows, col_tile=col_tile,
+                     band=int(band), col_tile=col_tile,
                      it=int(it), dt=int(dt), reseed_every=reseed_every,
                      backend=backend, interpret=resolve_interpret(interpret),
                      batch=batch,
@@ -393,7 +389,7 @@ def tile_cells(plan: SweepPlan) -> int:
     elif plan.backend == "rowstream":
         cells = l_a * plan.l_b
     else:               # the band engine at k = 1 and top-k, and nonnorm
-        li = ab_row_tile(l_a, plan.l_b, band) if plan.clamp_rows else l_a
+        li = ab_row_tile(l_a, plan.l_b, band)
         cells = sum(ceil(s1 - s0, band)
                     for s0, s1 in _ab_spans(l_a, plan.l_b, excl)) * band * li
     return cells * (plan.batch or 1)
@@ -648,7 +644,7 @@ def _execute_ab(plan: SweepPlan, stats) -> SweepResult:
         # sided: a lazily-accessed B side recomputes through the same plan
         da, ia, db, ib = ab_join_nonnorm(
             ts_a, ts_b, m, plan.exclusion, plan.band,
-            two_sided=two_sided, clamp_rows=plan.clamp_rows)
+            two_sided=two_sided)
         return SweepResult(da, ia, db, ib)
     if plan.harvest.k > 1:
         return _execute_ab_topk(plan, stats, two_sided)
@@ -693,7 +689,7 @@ def _execute_ab(plan: SweepPlan, stats) -> SweepResult:
     # the same plan with sides="both"
     fn = lambda c: ab_join_from_stats(                      # noqa: E731
         c, plan.exclusion, plan.band, plan.reseed_every, two_sided,
-        plan.clamp_rows, plan.col_tile, accum_dtype=plan.precision.accum)
+        plan.col_tile, accum_dtype=plan.precision.accum)
     if plan.batch is not None:
         fn = jax.vmap(fn)
     sa, sb = fn(stats)

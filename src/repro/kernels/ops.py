@@ -283,7 +283,7 @@ def hbm_bytes_per_cell(l: int, excl: int, it: int = DEFAULT_IT,
         j-side strips and the column-accumulator window are re-fetched once
         per (row-tile, diag-tile), so bytes/cell ~ c*(it+dt)/(it*dt) —
         driven down by larger tiles and narrower streams.
-    Used by benchmarks and EXPERIMENTS.md §Roofline-NATSA.
+    Used by `kernel_roofline` and `launch.roofline.matrix_profile_roofline`.
     """
     n_rows = -(-l // it)
     n_diags = -(-(l - excl) // dt)

@@ -1,9 +1,9 @@
 """Async round loop: double-buffered dispatch, block only at delivery.
 
-The round-barrier bottleneck the scaling bench exposed was host/device
-serialization: a loop that dispatches round k, synchronizes, THEN starts
-assembling round k+1 leaves the device idle through every host-side stats
-assembly and the host idle through every device sweep. JAX dispatch is
+The round-barrier bottleneck is host/device serialization: a loop that
+dispatches round k, synchronizes, THEN starts assembling round k+1 leaves
+the device idle through every host-side stats assembly and the host idle
+through every device sweep. JAX dispatch is
 asynchronous — a jitted call returns device futures immediately — so the
 fix is structural, not computational: keep up to `depth` dispatched rounds
 in flight, do the host assembly of round k+1 while round k's sweep runs,

@@ -99,7 +99,6 @@ SWEEP_PLAN_FIELDS = [
     "harvest",
     "swap_ab",
     "band",
-    "clamp_rows",
     "col_tile",
     "n_bands",
     "it",

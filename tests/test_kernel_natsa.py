@@ -81,3 +81,7 @@ def test_bytes_per_cell_model_sane():
     # streaming model: amortized HBM traffic per cell << one f32 per cell
     b = ops.hbm_bytes_per_cell(l=65536, excl=32, it=512, dt=32)
     assert 0 < b < 4.0, b
+    # at the kernel's default tiling, 16-bit streams model less traffic
+    f32 = ops.hbm_bytes_per_cell(l=65536, excl=64)
+    half = ops.hbm_bytes_per_cell(l=65536, excl=64, stream_bytes=2)
+    assert 0 < half < f32, (half, f32)

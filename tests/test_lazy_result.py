@@ -12,14 +12,9 @@ The contract under test (see core/result.py):
     harvest), first access re-executes the SAME plan two-sided — counted,
     cached, and still bitwise-equal;
   * sides a plan can never produce stay None.
-
-Plus the A/A null-drift test for the pinned-baseline bench harness: an
-honest cross-PR comparator must report "no change" when baseline and
-candidate are the same code.
 """
 
 import dataclasses
-import importlib.util
 import os
 
 import numpy as np
@@ -188,48 +183,6 @@ def test_streaming_query_sides_stay_none():
     assert res.kind == "ab" and res.p is not None
     # no lazy provider on the serving path: untouched sides are just None
     assert res.left_p is None and res.b_p is None and res.topk_p is None
-
-
-# -- pinned-baseline harness: A/A null drift ----------------------------------
-
-
-def _load_pinned():
-    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                        "pinned.py")
-    spec = importlib.util.spec_from_file_location("bench_pinned", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_pinned_harness_aa_null_covers_one():
-    """Baseline == candidate (same src/) must NOT report a change: the
-    bootstrap CI over the per-rep ratios has to cover 1.0, and the
-    min-based ratio has to sit near it. This is the calibration that makes
-    the cross-PR ratio rows trustworthy."""
-    pinned = _load_pinned()
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                       "src"))
-    # even rep count: the harness alternates arm order per rep, so pairs
-    # cancel monotone host drift (warmup/turbo) symmetrically
-    out = pinned.run_pinned(src, src, n=512, m=16, reps=4, inner=2,
-                            timeout=600.0)
-    assert len(out["baseline_us"]) == 4 and len(out["candidate_us"]) == 4
-    assert all(t > 0 for t in out["baseline_us"] + out["candidate_us"])
-    lo, hi = out["ratio_ci95"]
-    assert lo <= 1.0 <= hi, out
-    assert out["ci_covers_one"]
-    assert 0.5 < out["ratio_min"] < 2.0, out  # no phantom 2x swings on A/A
-
-
-def test_pinned_bootstrap_ci_is_deterministic_and_sane():
-    pinned = _load_pinned()
-    lo1, hi1 = pinned.bootstrap_ci([0.98, 1.01, 1.03, 0.99])
-    lo2, hi2 = pinned.bootstrap_ci([0.98, 1.01, 1.03, 0.99])
-    assert (lo1, hi1) == (lo2, hi2)       # seeded: CI artifacts reproduce
-    assert lo1 <= 1.0 <= hi1
-    lo, hi = pinned.bootstrap_ci([2.0, 2.1, 1.9, 2.05])
-    assert lo > 1.5                       # a real 2x regression IS detected
 
 
 if __name__ == "__main__":

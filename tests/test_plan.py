@@ -97,7 +97,7 @@ def test_engine_backend_plan_equals_direct_banded_call():
                                harvest="both")
     res = plan_mod.execute(plan, cross)
     sa, sb = ab_join_from_stats(cross, 0, DEFAULT_BAND, DEFAULT_RESEED,
-                                True, True, None)
+                                True, None)
     np.testing.assert_array_equal(np.asarray(res.dist),
                                   np.asarray(sa.to_distance(m)))
     np.testing.assert_array_equal(np.asarray(res.dist_b),
@@ -130,7 +130,7 @@ def test_batch_entries_equal_direct_vmap():
     c_stack = jax.tree.map(lambda *xs: jnp.stack(xs), *crosses)
     sa, _ = jax.vmap(
         lambda c: ab_join_from_stats(c, 0, DEFAULT_BAND, DEFAULT_RESEED,
-                                     False, True, None))(c_stack)
+                                     False, None))(c_stack)
     np.testing.assert_array_equal(np.asarray(abres.p),
                                   np.asarray(sa.to_distance(m)))
     np.testing.assert_array_equal(np.asarray(abres.i), np.asarray(sa.index))
@@ -225,9 +225,16 @@ def test_property_entry_equals_plan_execute_and_oracle(seed, m, kind):
     # nonnorm is engine-only
     (dict(window=16, l_a=391, l_b=81, normalize=False),
      dict(backend="engine", swap_ab=False)),
-    # unclamped A/B-comparison plan falls back to the band engine
-    (dict(window=16, l_a=391, l_b=81, clamp_rows=False),
-     dict(backend="engine", clamp_rows=False)),
+    # the benchmark cells' plans, pinned so a planner change cannot move a
+    # cell off its measured route: power-512k self-join on the kernel ...
+    (dict(window=1024, l_a=523265, exclusion=256, backend="kernel"),
+     dict(backend="kernel", col_tile=4096)),
+    # ... power-512k ab-query (4096-sample query vs the recording): rowstream
+    (dict(window=1024, l_a=3073, l_b=523265),
+     dict(backend="rowstream", swap_ab=False, exclusion=0)),
+    # ... seismology-64k through the default entry: the band engine
+    (dict(window=256, l_a=65281),
+     dict(backend="engine", exclusion=64, band=256, kind="self")),
     # self-join defaults: engine, default exclusion, default band
     (dict(window=128, l_a=16257),
      dict(backend="engine", exclusion=32, band=DEFAULT_BAND, kind="self")),
@@ -356,8 +363,6 @@ def test_planner_and_executor_reject_invalid_combinations():
         plan_mod.plan_sweep(16, 400, 20, backend="rowstream", k=24)
     with pytest.raises(ValueError, match="col_tile"):
         plan_mod.plan_sweep(16, 5000, k=4, col_tile=512)
-    with pytest.raises(ValueError, match="clamp_rows"):
-        plan_mod.plan_sweep(16, 400, 100, k=4, clamp_rows=False)
     with pytest.raises(ValueError, match="k"):
         plan_mod.plan_sweep(16, 100, k=0)
     # exclusion=0 self-join top-k would double-count the diagonal self-match

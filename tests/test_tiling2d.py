@@ -1,7 +1,7 @@
 """2-D tiled sweep: row-clamped AB bands + banked column accumulators.
 
-Property tests that (1) clamped AB band sweeps equal the pre-clamp
-full-height sweep and the numpy oracle across skewed shapes, (2) the
+Property tests that (1) clamped AB band sweeps equal the numpy oracle
+across skewed shapes, (2) the
 row-streamed fast path agrees with both, (3) banked column accumulators —
 engine `BankedColState` and the kernel's (n_banks, col_tile) outputs — match
 the flat accumulator bit-for-bit for several col_tile sizes including
@@ -45,27 +45,13 @@ def _series(n, seed=0, kind="walk"):
     (300, 300, 20, 0, 128),    # square, band wider than l/2
     (200, 90, 8, 0, 256),      # band wider than the whole diagonal space
 ])
-def test_clamped_band_sweep_equals_unclamped_and_oracle(na, nb, m, excl,
-                                                        band):
-    """The clamped sweep computes fewer cells but the SAME profiles as the
-    PR-2 full-height sweep (clamp_rows=False) and the brute-force oracle."""
+def test_clamped_band_sweep_equals_oracle(na, nb, m, excl, band):
+    """The row-clamped sweep computes only the tile rows inside the
+    rectangle, yet both profiles equal the brute-force oracle."""
     a = _series(na, seed=na + nb)
     b = _series(nb, seed=abs(na - nb) + 3)
     cross = compute_cross_stats_host(a, b, m)
-    sa_c, sb_c = ab_join_from_stats(cross, excl, band, 512, True, True)
-    sa_u, sb_u = ab_join_from_stats(cross, excl, band, 512, True, False)
-
-    def same(st_c, st_u):
-        # same recurrence over the same cells; XLA may reassociate the
-        # cumsum differently for the two tile lengths, so agreement is to
-        # f32 reassociation, with index flips allowed only on near-ties
-        c, u = np.asarray(st_c.corr), np.asarray(st_u.corr)
-        np.testing.assert_allclose(c, u, atol=1e-4)
-        mism = np.asarray(st_c.index) != np.asarray(st_u.index)
-        assert np.abs(c[mism] - u[mism]).max(initial=0) < 1e-4
-
-    same(sa_c, sa_u)
-    same(sb_c, sb_u)
+    sa_c, sb_c = ab_join_from_stats(cross, excl, band, 512, True)
     ref_a, _ = ab_join_bruteforce(jnp.asarray(a), jnp.asarray(b), m,
                                   exclusion=excl)
     ref_b, _ = ab_join_bruteforce(jnp.asarray(b), jnp.asarray(a), m,
@@ -83,7 +69,7 @@ def test_property_clamped_equals_oracle(na, nb, m, band):
     a = _series(na, seed=na * 7 + nb)
     b = _series(nb, seed=nb * 5 + 1, kind="noise")
     cross = compute_cross_stats_host(a, b, m)
-    sa, sb = ab_join_from_stats(cross, 0, band, 512, True, True)
+    sa, sb = ab_join_from_stats(cross, 0, band, 512, True)
     ref_a, _ = ab_join_bruteforce(jnp.asarray(a), jnp.asarray(b), m)
     ref_b, _ = ab_join_bruteforce(jnp.asarray(b), jnp.asarray(a), m)
     np.testing.assert_allclose(np.asarray(sa.to_distance(m)),
@@ -103,25 +89,14 @@ def test_ab_row_tile_bounds():
         assert hi - lo <= li
 
 
-def test_nonnorm_clamped_equals_unclamped():
-    """The unclamped full-height sweep survives only as an A/B-comparison
-    PLAN (`plan_sweep(..., clamp_rows=False)`); `ab_join` itself no longer
-    threads the legacy knob."""
-    from repro.core import plan as plan_mod
-
+def test_nonnorm_clamped_equals_oracle():
+    """The nonnorm AB join runs the same row-clamped band tiles; both
+    sides equal an f64 pairwise-distance oracle."""
     a = _series(400, seed=1, kind="noise")
     b = _series(90, seed=2, kind="noise")
     m = 10
     res_c = ab_join(a, b, m, normalize=False, return_b=True)
     da_c, db_c = res_c.p, res_c.b_p
-    plan_u = plan_mod.plan_sweep(m, 400 - m + 1, 90 - m + 1, normalize=False,
-                                 clamp_rows=False, harvest="both")
-    res_u = plan_mod.execute(
-        plan_u, (jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32)))
-    da_u, db_u = res_u.dist, res_u.dist_b
-    # agreement to f32 cumsum reassociation (tile lengths differ)
-    np.testing.assert_allclose(np.asarray(da_c), np.asarray(da_u), atol=1e-4)
-    np.testing.assert_allclose(np.asarray(db_c), np.asarray(db_u), atol=1e-4)
     la, lb = 400 - m + 1, 90 - m + 1
     wa = np.stack([a[k:k + m] for k in range(la)]).astype(np.float64)
     wb = np.stack([b[k:k + m] for k in range(lb)]).astype(np.float64)
@@ -145,7 +120,7 @@ def test_rowstream_matches_banded_and_oracle(na, nb, m, excl):
     b = _series(nb, seed=nb + 13)
     cross = compute_cross_stats_host(a, b, m)
     st_a, st_b = ab_join_rowstream(cross, excl, 512)
-    bd_a, bd_b = ab_join_from_stats(cross, excl, 64, 512, True, True)
+    bd_a, bd_b = ab_join_from_stats(cross, excl, 64, 512, True)
     np.testing.assert_allclose(np.asarray(st_a.to_distance(m)),
                                np.asarray(bd_a.to_distance(m)),
                                rtol=2e-3, atol=2e-3)
@@ -203,8 +178,8 @@ def test_engine_banked_colstate_equals_flat(col_tile):
     m, band = 16, 64
     cross = compute_cross_stats_host(a, b, m)
     assert col_tile > ab_row_tile(cross.l_a, cross.l_b, band) + band
-    sa0, sb0 = ab_join_from_stats(cross, 0, band, 512, True, True, None)
-    sa1, sb1 = ab_join_from_stats(cross, 0, band, 512, True, True, col_tile)
+    sa0, sb0 = ab_join_from_stats(cross, 0, band, 512, True, None)
+    sa1, sb1 = ab_join_from_stats(cross, 0, band, 512, True, col_tile)
     np.testing.assert_array_equal(np.asarray(sb0.corr), np.asarray(sb1.corr))
     np.testing.assert_array_equal(np.asarray(sb0.index), np.asarray(sb1.index))
     np.testing.assert_array_equal(np.asarray(sa0.corr), np.asarray(sa1.corr))
