@@ -7,9 +7,10 @@ Every phase goes through the entry points a user calls, at the sizes of the
 source paper (`configs/natsa.py`), on data made from fixed seeds by
 `data/pipeline.random_walk`, and checks its answer:
 
-  * self-join at seismology-64k and power-512k: `matrix_profile` (the
-    planner's band engine) and `natsa_matrix_profile` (the compiled Pallas
-    kernel) agree in correlation space within `precision.corr_tolerance`,
+  * self-join at seismology-64k and power-512k: an explicit band-engine
+    plan (the default `matrix_profile` itself takes the kernel on a TPU)
+    and `natsa_matrix_profile` (the compiled Pallas kernel) agree in
+    correlation space within `precision.corr_tolerance`,
     64 sampled rows of each match an exact f64 host reference, and a motif
     pair planted in the 64k series is recovered exactly by both;
   * AB join: a 4096-sample query against a 524288-sample reference through
@@ -129,9 +130,21 @@ def assert_compiled_kernel(ts_len: int, m: int, plan) -> float:
     return compile_s
 
 
+def engine_self_join(ts, m: int):
+    """The self-join through an explicit band-engine plan: on a TPU the
+    default `matrix_profile` entry takes the kernel."""
+    from repro.core import plan as plan_mod
+    from repro.core.result import build_result
+    from repro.core.zstats import compute_stats_host
+
+    plan = plan_mod.plan_sweep(m, ts.shape[0] - m + 1, backend="engine")
+    stats = compute_stats_host(ts, m)
+    return build_result(plan, plan_mod.execute(plan, stats), stats)
+
+
 def phase_self(name: str, n: int, m: int, seed: int, plant: bool):
     from repro.core import plan as plan_mod
-    from repro.core.matrix_profile import default_exclusion, matrix_profile
+    from repro.core.matrix_profile import default_exclusion
     from repro.core.precision import DEFAULT_PRECISION, corr_tolerance
     from repro.data.pipeline import random_walk
     from repro.kernels import ops
@@ -149,9 +162,10 @@ def phase_self(name: str, n: int, m: int, seed: int, plant: bool):
     compile_s = assert_compiled_kernel(n, m, plan)
 
     t0 = time.perf_counter()
-    res_e = matrix_profile(ts, m)
+    res_e = engine_self_join(ts, m)
     p_e = np.asarray(res_e.p)
     t_engine = time.perf_counter() - t0
+    assert res_e.backend == "engine", res_e.backend
     t0 = time.perf_counter()
     res_k = ops.natsa_matrix_profile(ts, m)
     p_k = np.asarray(res_k.p)

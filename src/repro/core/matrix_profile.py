@@ -750,11 +750,18 @@ def matrix_profile(ts, window: int, exclusion: int | None = None,
     all-f32 pipeline bitwise; "bf16" halves the streamed bytes per cell and
     switches the sweep to the recurrence-free dot-product tile path.
 
+    Route: on a TPU the default call — z-normalized, k = 1, the default
+    f32 precision, `band` and `reseed_every` left at their defaults — runs
+    the NATSA Pallas kernel (`kernels.natsa_mp`). Everywhere else (the CPU,
+    top-k, nonnorm, 16-bit or f64-accumulating precision, a tuned `band` or
+    `reseed_every`) it runs the band-diagonal engine; `band` and
+    `reseed_every` are the engine's knobs and apply to it only.
+
     Thin entry: builds a `SweepPlan` (core.plan) and runs it through the
-    executor — the band-engine choice, exclusion default, harvest wiring and
+    executor — the backend choice, exclusion default, harvest wiring and
     precision policy all live in the planner. Stream precompute happens
     host-side in f64 (see zstats.compute_stats_host — f32 cancellation is
-    catastrophic on offset data); the O(l^2) diagonal engine runs on device
+    catastrophic on offset data); the O(l^2) diagonal sweep runs on device
     streaming the plan's stream dtype, touching each upper-triangle cell
     once and harvesting both profile sides from it.
     """

@@ -25,6 +25,12 @@ Planner heuristics centralized here (formerly scattered per entry point):
     `AB_ROWSTREAM_MAX_ROWS` takes the row-streamed scan (the fastest exact
     path on skewed shapes); huge near-square joins and every partitioned /
     batched / nonnorm sweep take the band-diagonal engine.
+  * self-join route: on a TPU (the compiled kernel, not the interpreter)
+    the default self-join — z-normalized, k = 1, unbatched, the default
+    f32 precision, `band` and `reseed_every` at their defaults, and at
+    least one diagonal past the exclusion zone — takes the Pallas kernel;
+    top-k, batched, nonnorm, 16-bit, f64-accumulating and engine-tuned
+    self-joins, and every self-join on the CPU, take the band engine.
   * `auto_col_tile` banking: kernel self-joins resolve their column
     accumulator policy AT PLAN TIME (col_tile pinned in the plan: 0 = one
     flat bank, else the bank width); AB kernel spans resolve per span inside
@@ -175,6 +181,15 @@ def _kernel_self_col_tile(l: int, excl: int, it: int, dt: int,
     return 0 if ct is None else ct
 
 
+def _compiled_kernel_runs(interpret: bool | None) -> bool:
+    """Whether a kernel plan would run the compiled Mosaic kernel: the
+    Pallas mode resolves to compiled, and JAX runs on a TPU or the caller
+    pinned `interpret=False` (a compile for a described TPU elsewhere)."""
+    if resolve_interpret(interpret):
+        return False
+    return interpret is not None or jax.default_backend() == "tpu"
+
+
 def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
                exclusion: int | None = None, normalize: bool = True,
                harvest: str | HarvestSpec = "merged", k: int = 1,
@@ -190,7 +205,8 @@ def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
     make inline. `l_a`/`l_b` are SUBSEQUENCE counts (n - window + 1);
     `backend=None` lets the planner choose (entry points only force a backend
     when the user asked for a specific engine, e.g. the Pallas kernel ops or
-    the scheduler's SPMD rounds).
+    the scheduler's SPMD rounds; the self-join route rule is in the module
+    docstring).
 
     `harvest` is the sides string ("merged" | "row" | "both") or a full
     `HarvestSpec`. The DEFAULT is the minimal "merged" harvest — plan only
@@ -264,6 +280,11 @@ def plan_sweep(window: int, l_a: int, l_b: int | None = None, *,
                 and min(l_a, l_b) <= AB_ROWSTREAM_MAX_ROWS \
                 and spec.k <= min(l_a, l_b):
             backend = "rowstream"
+        elif (kind == "self" and normalize and not topk and batch is None
+              and prec.is_default and int(band) == DEFAULT_BAND
+              and reseed_every == DEFAULT_RESEED and l_a > excl
+              and _compiled_kernel_runs(interpret)):
+            backend = "kernel"
         else:
             backend = "engine"
     if backend not in BACKENDS:

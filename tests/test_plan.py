@@ -232,9 +232,33 @@ def test_property_entry_equals_plan_execute_and_oracle(seed, m, kind):
     # ... power-512k ab-query (4096-sample query vs the recording): rowstream
     (dict(window=1024, l_a=3073, l_b=523265),
      dict(backend="rowstream", swap_ab=False, exclusion=0)),
-    # ... seismology-64k through the default entry: the band engine
+    # ... seismology-64k through the default entry: on the CPU (the
+    # kernel would run in the interpreter) the band engine
     (dict(window=256, l_a=65281),
      dict(backend="engine", exclusion=64, band=256, kind="self")),
+    # ... and where the compiled kernel runs (pinned here by
+    # interpret=False; on a TPU the default resolves so): the kernel
+    (dict(window=256, l_a=65281, interpret=False),
+     dict(backend="kernel", exclusion=64, col_tile=4096, kind="self")),
+    # every other self-join keeps the band engine even so: top-k, batched,
+    # 16-bit streams, f64 accumulation, nonnorm, a tuned band or reseed
+    (dict(window=256, l_a=65281, interpret=False, k=4),
+     dict(backend="engine", col_tile=None)),
+    (dict(window=256, l_a=65281, interpret=False, batch=8),
+     dict(backend="engine", batch=8)),
+    (dict(window=256, l_a=65281, interpret=False, precision="bf16"),
+     dict(backend="engine")),
+    (dict(window=256, l_a=65281, interpret=False, precision="f64"),
+     dict(backend="engine")),
+    (dict(window=256, l_a=65281, interpret=False, normalize=False),
+     dict(backend="engine")),
+    (dict(window=256, l_a=65281, interpret=False, band=128),
+     dict(backend="engine", band=128)),
+    (dict(window=256, l_a=65281, interpret=False, reseed_every=None),
+     dict(backend="engine", reseed_every=None)),
+    # no diagonal past the exclusion zone: nothing for the kernel to sweep
+    (dict(window=16, l_a=4, exclusion=4, interpret=False),
+     dict(backend="engine")),
     # self-join defaults: engine, default exclusion, default band
     (dict(window=128, l_a=16257),
      dict(backend="engine", exclusion=32, band=DEFAULT_BAND, kind="self")),
@@ -273,6 +297,51 @@ def test_plan_sweep_choices(kwargs, expect):
     plan = plan_mod.plan_sweep(window, l_a, l_b, **kwargs)
     for field, want in expect.items():
         assert getattr(plan, field) == want, (field, getattr(plan, field))
+
+
+def test_default_self_join_on_a_tpu_plans_the_compiled_kernel(monkeypatch):
+    """With JAX on a TPU, the unpinned default resolves to the compiled
+    kernel (`resolve_interpret` and the planner read the same backend);
+    an explicit interpret=True keeps the band engine there."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan = plan_mod.plan_sweep(256, 65281)
+    assert (plan.backend, plan.interpret, plan.col_tile) == ("kernel", False,
+                                                            4096)
+    assert plan_mod.plan_sweep(256, 65281, interpret=True).backend == "engine"
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    assert plan_mod.plan_sweep(256, 65281).backend == "engine"
+
+
+def test_default_entry_kernel_route_equals_engine_route():
+    """The default self-join's two routes — the kernel (here in the
+    interpreter) and the band engine — give the same profile."""
+    from repro.core.result import build_result
+    from repro.core.zstats import dist_to_corr
+
+    ts = _series(600, seed=77, kind="walk")
+    m = 20
+    l = ts.size - m + 1
+    stats = compute_stats_host(ts, m)
+    routes = {}
+    for backend in ("engine", "kernel"):
+        plan = plan_mod.plan_sweep(m, l, backend=backend, interpret=True)
+        routes[backend] = build_result(plan, plan_mod.execute(plan, stats),
+                                       stats)
+    eng, ker = routes["engine"], routes["kernel"]
+    assert (eng.backend, ker.backend) == ("engine", "kernel")
+    assert eng.exclusion == ker.exclusion
+    np.testing.assert_allclose(np.asarray(ker.p), np.asarray(eng.p),
+                               atol=1e-3)
+    # on the CPU the entry itself takes the engine route, bitwise
+    np.testing.assert_array_equal(np.asarray(matrix_profile(ts, m).p),
+                                  np.asarray(eng.p))
+    ce, ck = (np.asarray(dist_to_corr(jnp.asarray(r.p), m))
+              for r in (eng, ker))
+    # argmax ties can differ only where correlations are ~equal
+    mism = np.asarray(ker.i) != np.asarray(eng.i)
+    assert np.abs(ck[mism] - ce[mism]).max(initial=0) < 5e-4
 
 
 def test_plan_geometry_spans():

@@ -121,7 +121,8 @@ def test_ab_join_kernel_compiles_for_v5e(one_chip, workload, n_a):
 @pytest.mark.parametrize("case", ["self", "ab", "ab-excluded"])
 def test_compiled_kernel_with_dead_tiles_matches_engine_on_chip(case):
     """On a TPU, the compiled kernel at the default 1024 x 64 tiles, at
-    shapes whose grids hold dead tiles, against the band engine: a banked
+    shapes whose grids hold dead tiles, against another route (explicit
+    band-engine plans for the self-joins, rowstream for the AB): a banked
     self-join with l = 8*1024 + 1 (its whole last row tile dead), an AB
     join with prefix and suffix dead tiles, and an excluded AB (two
     launches). Skipped off-TPU, where the interpreter runs the other
@@ -130,18 +131,28 @@ def test_compiled_kernel_with_dead_tiles_matches_engine_on_chip(case):
     import numpy as np
 
     from repro.core import plan as plan_mod
-    from repro.core.matrix_profile import ab_join, matrix_profile
-    from repro.core.zstats import dist_to_corr
+    from repro.core.matrix_profile import ab_join
+    from repro.core.result import build_result
+    from repro.core.zstats import compute_stats_host, dist_to_corr
 
     if jax.default_backend() != "tpu":
         pytest.skip("runs the compiled kernel: needs a TPU")
+
+    def engine_self_join(ts, m, exclusion=None):
+        # an explicit engine plan: on a TPU the default self-join entry
+        # takes the kernel itself
+        plan = plan_mod.plan_sweep(m, ts.size - m + 1, exclusion=exclusion,
+                                   backend="engine")
+        stats = compute_stats_host(ts, m)
+        return build_result(plan, plan_mod.execute(plan, stats), stats)
+
     m = 128
     rng = np.random.default_rng(2147483901)
     a = np.cumsum(rng.normal(size=8 * 1024 + m))
     b = np.cumsum(rng.normal(size=3000))
     if case == "self":
         got = ops.natsa_matrix_profile(a, m)
-        want = matrix_profile(a, m)
+        want = engine_self_join(a, m)
         plan = plan_mod.plan_sweep(m, a.size - m + 1, backend="kernel")
     elif case == "ab":
         got = ops.natsa_ab_join(b, a, m)
@@ -151,9 +162,10 @@ def test_compiled_kernel_with_dead_tiles_matches_engine_on_chip(case):
     else:
         excl = default_exclusion(m)
         got = ops.natsa_ab_join(a, a, m, exclusion=excl)
-        want = matrix_profile(a, m, exclusion=excl)
+        want = engine_self_join(a, m, exclusion=excl)
         plan = plan_mod.plan_sweep(m, a.size - m + 1, a.size - m + 1,
                                    exclusion=excl, backend="kernel")
+    assert want.backend != "kernel", want.backend
     assert plan_mod.skipped_tiles(plan) > 0
     cg, cw = (np.asarray(dist_to_corr(jnp.asarray(r.p), m))
               for r in (got, want))
